@@ -7,7 +7,7 @@ PY ?= python
 # passes --format through; exit codes are unchanged either way
 LINT_FORMAT ?=
 
-.PHONY: lint lockwatch test chaos trace-smoke profile-smoke incident-smoke critpath-smoke multichip-smoke das-smoke swarm-smoke ingress-smoke device-resident-smoke mesh-live t1-budget bench-check native native-sanitize native-sanitize-tsan native-sanitize-asan bench
+.PHONY: lint lockwatch test chaos trace-smoke profile-smoke incident-smoke critpath-smoke multichip-smoke das-smoke swarm-smoke ingress-smoke device-resident-smoke mesh-live t1-budget native native-sanitize native-sanitize-tsan native-sanitize-asan bench
 
 ## celint: concurrency & determinism static analysis (exit 1 on findings)
 lint:
@@ -143,12 +143,6 @@ mesh-live:
 ## single non-slow test exceeded 30 s (the 870 s tier-1 run truncates)
 t1-budget:
 	$(PY) tools/t1_budget.py
-
-## bench regression watchdog: compares every headline metric's latest
-## BENCH_r*.json value against best-so-far (25% tolerance); exits loud
-## on regression
-bench-check:
-	$(PY) tools/bench_check.py
 
 ## (re)build the production native library
 native:

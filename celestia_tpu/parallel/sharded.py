@@ -32,8 +32,8 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from celestia_tpu.appconsts import NAMESPACE_SIZE, SHARE_SIZE
 from celestia_tpu.ops import nmt as nmt_ops
@@ -178,7 +178,7 @@ def _build_sharded_fn(mesh: Mesh, k: int, batched: bool, codec: str):
             mesh=mesh,
             in_specs=P("row", None, None),
             out_specs=(P("row", None, None, None), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(fn)
 
@@ -193,7 +193,7 @@ def _build_sharded_fn(mesh: Mesh, k: int, batched: bool, codec: str):
             P("data"),
             P("data"),
         ),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

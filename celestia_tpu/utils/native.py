@@ -1,8 +1,10 @@
 """ctypes bindings for the native C++ host library (native/celestia_native.cpp).
 
-Builds the shared object on demand with g++ (cached by source mtime) and
-exposes the same operations as the device kernels — used as the CPU
-comparison leg in bench.py and as a host fallback.  If no compiler is
+Builds the shared object on demand with g++ (keyed by a stamp of the
+source and this host's resolved ``-march=native`` flags, so a tree copied
+to another CPU rebuilds) and exposes the same operations as the device
+kernels — used as the CPU comparison leg in bench.py and as a host
+fallback.  If no compiler is
 available the module degrades gracefully (``available()`` returns False).
 """
 
@@ -87,19 +89,44 @@ def clear_poison(force: bool = False) -> None:
         _poison_reason = None
 
 
-def _build() -> bool:
+_SO_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-pthread")
+
+
+def _host_target() -> bytes:
+    """The compiler's resolved target flags for ``-march=native`` on THIS
+    host: part of every build stamp, so a binary built for another CPU is
+    never reused."""
+    return subprocess.run(
+        ["g++", "-march=native", "-Q", "--help=target"],
+        check=True, capture_output=True, timeout=60,
+    ).stdout
+
+
+def ensure_built(src: Path, out: Path, flags) -> bool:
+    """Build ``out`` from ``src`` with g++ unless a stamp beside it
+    (``<out>.stamp``) records a build from these exact source bytes,
+    flags and host target.  The build lands via a temporary file and an
+    atomic rename, so concurrent processes never load a half-written
+    binary.  False when no compiler is available or the build fails."""
+    import hashlib
+
+    stamp = Path(str(out) + ".stamp")
     try:
+        h = hashlib.sha256(src.read_bytes())
+        h.update(" ".join(flags).encode())
+        h.update(_host_target())
+        key = h.hexdigest()
+        if out.exists() and stamp.exists() and stamp.read_text() == key:
+            return True
+        tmp = out.with_name(f"{out.name}.tmp{os.getpid()}")
         subprocess.run(
-            [
-                "g++", "-O3", "-march=native", "-shared", "-fPIC", "-pthread",
-                str(_SRC), "-o", str(_SO),
-            ],
-            check=True,
-            capture_output=True,
-            timeout=300,
+            ["g++", *flags, str(src), "-o", str(tmp)],
+            check=True, capture_output=True, timeout=300,
         )
+        os.replace(tmp, out)
+        stamp.write_text(key)
         return True
-    except (subprocess.SubprocessError, FileNotFoundError):
+    except (subprocess.SubprocessError, OSError):
         return False
 
 
@@ -113,9 +140,8 @@ def _load() -> Optional[ctypes.CDLL]:
     if _SO_OVERRIDE:
         if not _SO.exists():
             return None  # sanitizer harness must have built it already
-    elif not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-        if not _build():
-            return None
+    elif not ensure_built(_SRC, _SO, _SO_FLAGS):
+        return None
     try:
         lib = ctypes.CDLL(str(_SO))
     except OSError:

@@ -1,12 +1,10 @@
-"""The bench regression watchdog (tools/bench_check.py, `make
-bench-check`): the recorded BENCH_r01..r05 trajectory must pass, a
-synthetic regressed round must fail loudly, and the comparison
-semantics (per-metric series, best-so-far, direction, tolerance,
-unparsed rounds) are pinned here."""
+"""The bench regression watchdog (tools/bench_check.py): a synthetic
+regressed round must fail loudly, and the comparison semantics
+(per-metric series, best-so-far, direction, tolerance, unparsed rounds)
+are pinned here on synthetic rounds."""
 
 import importlib.util
 import json
-import shutil
 import subprocess
 import sys
 from pathlib import Path
@@ -41,32 +39,20 @@ def _write_rounds(tmp_path, rounds):
         (tmp_path / f"BENCH_r{i:02d}.json").write_text(json.dumps(doc))
 
 
-def test_recorded_trajectory_passes():
-    """Acceptance: `make bench-check` on the real BENCH_r01..r05 files."""
-    out = subprocess.run(
-        [sys.executable, "tools/bench_check.py"],
-        capture_output=True, text=True, cwd=REPO,
-    )
-    assert out.returncode == 0, out.stderr
-    rep = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rep["bench_check"] == "ok"
-    assert rep["metrics_checked"] > 0
-    # the crashed r04 run contributes nothing but is reported, not hidden
-    assert "BENCH_r04" in rep["unparsed_rounds"]
-
-
 def test_synthetic_regression_fails_loud(tmp_path):
     """Acceptance: a regressed round must exit non-zero and NAME the
     regressed metric."""
-    for f in sorted(REPO.glob("BENCH_r*.json")):
-        shutil.copy(f, tmp_path / f.name)
-    reg = _round(
-        6,
-        metric="extend_block_128x128_p50_device_ms",
-        value=40.0,  # best so far is ~8.4 ms
-        extras={"filter_512_pfb_ms": 500.0},  # best so far 83.3 ms
-    )
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(reg))
+    _write_rounds(tmp_path, [
+        _round(1, value=10.5, extras={"filter_512_pfb_ms": 153.5}),
+        _round(2, value=8.4, extras={"filter_512_pfb_ms": 152.7}),
+        _round(3, parsed=False),  # a crashed round contributes nothing
+        _round(4, value=8.6, extras={"filter_512_pfb_ms": 83.3}),
+        _round(
+            5,
+            value=40.0,  # best so far is 8.4 ms
+            extras={"filter_512_pfb_ms": 500.0},  # best so far 83.3 ms
+        ),
+    ])
     out = subprocess.run(
         [sys.executable, "tools/bench_check.py", "--dir", str(tmp_path)],
         capture_output=True, text=True, cwd=REPO,

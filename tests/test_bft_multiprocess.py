@@ -31,7 +31,6 @@ REPO = Path(__file__).resolve().parents[1]
 
 _CHILD_ENV = {
     **os.environ,
-    "CELESTIA_JAX_PLATFORM": "cpu",
     "JAX_PLATFORMS": "cpu",
     "TF_CPP_MIN_LOG_LEVEL": "3",
 }

@@ -451,7 +451,6 @@ def test_mesh_three_os_processes(tmp_path_factory):
     REPO = Path(__file__).resolve().parents[1]
     env = {
         **os.environ,
-        "CELESTIA_JAX_PLATFORM": "cpu",
         "JAX_PLATFORMS": "cpu",
         "TF_CPP_MIN_LOG_LEVEL": "3",
     }

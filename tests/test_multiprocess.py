@@ -24,7 +24,6 @@ _CHILD_ENV = {
     **os.environ,
     # followers must not contend with the parent pytest process (or the
     # leader) for the single TPU device
-    "CELESTIA_JAX_PLATFORM": "cpu",
     "JAX_PLATFORMS": "cpu",
     "TF_CPP_MIN_LOG_LEVEL": "3",
 }

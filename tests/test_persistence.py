@@ -248,7 +248,6 @@ def test_kill9_cli_node_restarts_and_catches_up(tmp_path):
     and keeps producing from where it crashed."""
     env = {
         **os.environ,
-        "CELESTIA_JAX_PLATFORM": "cpu",
         "JAX_PLATFORMS": "cpu",
         "TF_CPP_MIN_LOG_LEVEL": "3",
     }

@@ -27,11 +27,9 @@ BIN = REPO / "native" / "wire_decoder"
 
 @pytest.fixture(scope="module")
 def decoder():
-    if not BIN.exists() or BIN.stat().st_mtime < SRC.stat().st_mtime:
-        subprocess.run(
-            ["g++", "-O2", "-o", str(BIN), str(SRC)],
-            check=True, capture_output=True, timeout=120,
-        )
+    from celestia_tpu.utils.native import ensure_built
+
+    assert ensure_built(SRC, BIN, ("-O2",)), "wire_decoder build failed"
 
     def run(mode: str, payload: str) -> dict:
         out = subprocess.run(

@@ -1,6 +1,7 @@
-"""bench_check: the bench-regression watchdog (`make bench-check`).
+"""bench_check: the bench-regression watchdog.
 
-Reads the BENCH_r*.json trajectory and compares every headline metric's
+Reads a BENCH_r*.json trajectory (``--dir``; the repo holds no records
+until the cell benchmark writes them) and compares every headline metric's
 LATEST recorded value against the best value any EARLIER round recorded
 for the same metric name, with a stated tolerance.  Exits loud (rc 1,
 one line per regression) when the latest value is worse than

@@ -75,7 +75,6 @@ def _readline_deadline(proc, timeout_s: float = 180.0):
 def _env(extra=None):
     env = {
         **os.environ,
-        "CELESTIA_JAX_PLATFORM": "cpu",
         "JAX_PLATFORMS": "cpu",
         "TF_CPP_MIN_LOG_LEVEL": "3",
         "CELESTIA_TPU_TRACE": "1",

@@ -233,7 +233,6 @@ def leg2() -> int:
     base = tempfile.mkdtemp(prefix="profile-smoke-")
     env = {
         **os.environ,
-        "CELESTIA_JAX_PLATFORM": "cpu",
         "JAX_PLATFORMS": "cpu",
         "TF_CPP_MIN_LOG_LEVEL": "3",
         "CELESTIA_TPU_TRACE": "1",

@@ -177,7 +177,6 @@ def two_node_leg() -> int:
 
     env = {
         **os.environ,
-        "CELESTIA_JAX_PLATFORM": "cpu",
         "JAX_PLATFORMS": "cpu",
         "TF_CPP_MIN_LOG_LEVEL": "3",
         "CELESTIA_TPU_TRACE": "1",

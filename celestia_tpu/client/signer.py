@@ -124,15 +124,11 @@ class Signer:
             return res
         return self.confirm_tx(res.tx_hash)
 
-    def submit_pay_for_blob(
-        self,
-        blobs: Sequence[Blob],
-        gas_limit: Optional[int] = None,
-        **opts,
-    ) -> SubmitResult:
-        """SubmitPayForBlob (signer.go:162-169): build MsgPayForBlobs with
-        share commitments, wrap the signed tx + blobs in a BlobTx envelope."""
-        blobs = list(blobs)
+    def pay_for_blob_tx(self, blobs: Sequence[Blob], gas_limit: Optional[int] = None):
+        """The MsgPayForBlobs for ``blobs`` (share commitments computed
+        once); returns ``sign(**opts) -> bytes``, the signed BlobTx
+        envelope at the signer's sequence or ``sequence=``."""
+        blobs = tuple(blobs)
         msg = MsgPayForBlobs(
             signer=self.address,
             namespaces=tuple(b.namespace.raw for b in blobs),
@@ -143,11 +139,22 @@ class Signer:
         if gas_limit is None:
             gas_limit = estimate_gas([len(b.data) for b in blobs])
 
-        def make_raw() -> bytes:
+        def sign(**opts) -> bytes:
             tx = self.sign_tx([msg], gas_limit=gas_limit, **opts)
-            return BlobTx(tx=tx.marshal(), blobs=tuple(blobs)).marshal()
+            return BlobTx(tx=tx.marshal(), blobs=blobs).marshal()
 
-        res = self._broadcast(make_raw)
+        return sign
+
+    def submit_pay_for_blob(
+        self,
+        blobs: Sequence[Blob],
+        gas_limit: Optional[int] = None,
+        **opts,
+    ) -> SubmitResult:
+        """SubmitPayForBlob (signer.go:162-169): build MsgPayForBlobs with
+        share commitments, wrap the signed tx + blobs in a BlobTx envelope."""
+        make_raw = self.pay_for_blob_tx(blobs, gas_limit)
+        res = self._broadcast(lambda: make_raw(**opts))
         if res.code != 0:
             return res
         return self.confirm_tx(res.tx_hash)

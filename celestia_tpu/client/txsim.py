@@ -166,3 +166,37 @@ def run(
         node.app.accounts.get_or_create(addr)
         signers.append(Signer(node, key))
     return _drive(sequences, signers, iterations, seed)
+
+
+# ---------------------------------------------------------------------------
+# pre-signed PayForBlob traffic (bench.py, chip_smoke.py)
+# ---------------------------------------------------------------------------
+
+
+def random_blob(rng: np.random.Generator, index: int, size: int) -> Blob:
+    """``size`` random bytes under the ``index``-th of 250 v0 namespaces."""
+    return Blob(
+        Namespace.v0(bytes([index % 250 + 1]) * 10),
+        rng.integers(0, 256, size, dtype=np.uint8).tobytes(),
+    )
+
+
+def signed_pfb_txs(
+    node,
+    keys: TypingSequence[PrivateKey],
+    n_tx: int,
+    blob_bytes: int,
+    rng: np.random.Generator,
+    first_namespace: int = 0,
+) -> List[bytes]:
+    """``n_tx`` signed single-blob PayForBlob BlobTxs of ``blob_bytes``
+    each, round-robin over ``keys`` from sequence 0 (tx i at sequence
+    i // len(keys)), without submitting them: a block's worth of traffic
+    for ``App.prepare_proposal`` / ``filter_txs`` / square building."""
+    signers = [Signer(node, key) for key in keys]
+    return [
+        signers[i % len(signers)].pay_for_blob_tx(
+            [random_blob(rng, first_namespace + i, blob_bytes)]
+        )(sequence=i // len(signers))
+        for i in range(n_tx)
+    ]

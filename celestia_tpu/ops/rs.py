@@ -347,6 +347,48 @@ def _simulate_schedule(avail: np.ndarray, k: int):
     )
 
 
+def _repair_plan(avail: np.ndarray, with_roots: bool):
+    """The host peel of ``avail`` and the device program that consumes
+    it: ``(fn, (row_known, row_mask, col_known, col_mask))``, with ``fn``
+    None when the mask needs more than _MAX_DEVICE_PHASES phases (the
+    host path takes those)."""
+    n2 = avail.shape[0]
+    k = n2 // 2
+    schedule = _simulate_schedule(avail, k)  # bools only, ~1 ms at k=128
+    if schedule is None:  # nothing missing: zero phases
+        rk = np.zeros((0, n2, k), dtype=np.uint8)
+        rm = np.zeros((0, n2), dtype=bool)
+        schedule = (rk, rm, rk.copy(), rm.copy())
+    phases = schedule[0].shape[0]
+    if phases > _MAX_DEVICE_PHASES:
+        return None, schedule
+    chunk = min(n2, max(1, 8192 // k))  # ~bounded D_bits working set
+    # codec resolved HERE (not inside the lru_cached builder) so a codec
+    # switch can never serve a stale cached program
+    fn = _repair_verify_fn(k, phases, chunk, with_roots, gf256.active_codec())
+    return fn, schedule
+
+
+def repair_program(available: np.ndarray, with_roots: bool = True):
+    """The program ``repair_square_device`` dispatches for this
+    availability mask, with its arguments as ``jax.ShapeDtypeStruct``s:
+    ``(fn, args)`` for an ahead-of-time ``fn.lower(*args).compile()``
+    that lands on the same compile-cache entry."""
+    avail = np.asarray(available, dtype=bool)
+    fn, schedule = _repair_plan(avail, with_roots)
+    if fn is None:
+        raise ValueError(
+            "this mask peels in more than "
+            f"{_MAX_DEVICE_PHASES} phases: it repairs on the host"
+        )
+    n2 = avail.shape[0]
+    args = (
+        jax.ShapeDtypeStruct((n2, n2, SHARE_SIZE), jnp.uint8),
+        jax.ShapeDtypeStruct(avail.shape, jnp.bool_),
+    ) + tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in schedule)
+    return fn, args
+
+
 def repair_square_device(
     eds: np.ndarray,
     available: np.ndarray,
@@ -386,41 +428,29 @@ def repair_square_device(
         raise ValueError("eds must be (2k, 2k, B) with matching availability mask")
     masked = np.where(avail[:, :, None], provided, 0).astype(np.uint8)
 
+    with_roots = row_roots is not None or col_roots is not None
     t0 = _t.time()
-    schedule = _simulate_schedule(avail, k)  # bools only, ~1 ms at k=128
-    if schedule is None:
-        P = 0
-        rk = np.zeros((0, n2, k), dtype=np.uint8)
-        rm = np.zeros((0, n2), dtype=bool)
-        ck, cm = rk.copy(), rm.copy()
-    else:
-        rk, rm, ck, cm = schedule
-        P = rk.shape[0]
-    if P > _MAX_DEVICE_PHASES:
+    fn, (rk, rm, ck, cm) = _repair_plan(avail, with_roots)
+    if fn is None:
         # degenerate (adversarial) masks: don't let each one compile its
         # own P-phase device program — the host path handles any depth.
         # (The bulk upload is dispatched AFTER this check so the
         # fallback never pays a wasted 8 MiB transfer.)
         out = repair_square(eds, available, row_roots, col_roots)
         return jnp.asarray(out) if return_device else out
-    chunk = min(n2, max(1, 8192 // k))  # ~bounded D_bits working set
-    with_roots = row_roots is not None or col_roots is not None
     # dispatch the bulk upload asynchronously (jnp.asarray starts the
     # transfer; nothing blocks on it) so the ~8 MiB square streams while
     # the index tensors upload and the program dispatches (VERDICT r3 #6)
     masked_dev = jnp.asarray(masked)
     t1 = _t.time()
-    # codec resolved HERE (not inside the lru_cached builder) so a codec
-    # switch can never serve a stale cached program
     from celestia_tpu.utils import devprof
 
-    fn = _repair_verify_fn(k, P, chunk, with_roots, gf256.active_codec())
     fn_args = (
         masked_dev, jnp.asarray(avail),
         jnp.asarray(rk), jnp.asarray(rm),
         jnp.asarray(ck), jnp.asarray(cm),
     )
-    d = devprof.dispatch("rs_repair_verify", k=k, phases=P)
+    d = devprof.dispatch("rs_repair_verify", k=k, phases=rk.shape[0])
     out = fn(*fn_args)
     d.done(out)
     repaired_dev, mismatch_dev, provided_mismatch_dev, roots_dev = out

@@ -60,43 +60,52 @@ def log(msg):
 # ---------------------------------------------------------------------------
 
 _compile_lock = threading.Lock()
-_compiles = {}  # fun_name -> [count, seconds]
-_cache_hits = [0]
+# fun_name -> [programs, seconds, persistent-cache hits, retrieval seconds]
+_compiles = {}
+_thread = threading.local()  # a hit's retrieval time, until its compile event
 
 
 def _on_duration(event, duration, **kw):
-    if event == "/jax/core/compile/backend_compile_duration":
+    # JAX times the compile of a program (backend_compile_duration) around
+    # its persistent-cache read; a hit first records its retrieval
+    # (cache_retrieval_time_sec, no program name) on the same thread
+    if event == "/jax/compilation_cache/cache_retrieval_time_sec":
+        _thread.retrieval = float(duration)
+    elif event == "/jax/core/compile/backend_compile_duration":
+        retrieval = getattr(_thread, "retrieval", None)
+        _thread.retrieval = None
         with _compile_lock:
-            rec = _compiles.setdefault(kw.get("fun_name", "?"), [0, 0.0])
+            rec = _compiles.setdefault(kw.get("fun_name", "?"), [0, 0.0, 0, 0.0])
             rec[0] += 1
             rec[1] += float(duration)
-
-
-def _on_event(event, **kw):
-    if event == "/jax/compilation_cache/cache_hits":
-        with _compile_lock:
-            _cache_hits[0] += 1
+            if retrieval is not None:
+                rec[2] += 1
+                rec[3] += retrieval
 
 
 def install_compile_listeners():
     import jax
 
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
-    jax.monitoring.register_event_listener(_on_event)
 
 
 def report_compiles():
+    """Per program: compile events and seconds, and how many of them were
+    persistent-cache hits with the seconds spent retrieving those."""
     with _compile_lock:
         items = sorted(_compiles.items(), key=lambda kv: -kv[1][1])
-        hits = _cache_hits[0]
-    small = [(n, c, s) for n, (c, s) in items if s < 0.5]
-    for name, (count, secs) in items:
+    small = [rec for _n, rec in items if rec[1] < 0.5]
+    for name, (count, secs, hits, retrieval) in items:
         if secs >= 0.5:
-            log(f"compile {name}: {count} program(s), {secs:.3f} s")
+            log(
+                f"compile {name}: {count} program(s), {secs:.3f} s; "
+                f"{hits} persistent-cache hit(s), retrieval {retrieval:.3f} s"
+            )
     log(
-        f"compile (programs under 0.5 s each): {sum(c for _, c, _ in small)} "
-        f"program(s), {sum(s for _, _, s in small):.3f} s; "
-        f"persistent-cache hits: {hits}"
+        f"compile (programs under 0.5 s each): {sum(r[0] for r in small)} "
+        f"program(s), {sum(r[1] for r in small):.3f} s; "
+        f"{sum(r[2] for r in small)} persistent-cache hit(s); "
+        f"all programs: {sum(r[2] for _n, r in items)} hit(s)"
     )
 
 
@@ -166,23 +175,15 @@ def check_dah(dah, row_roots, col_roots, data_root, what):
 # ---------------------------------------------------------------------------
 
 
-def make_blob(rng, tag, size):
-    from celestia_tpu.da.blob import Blob
-    from celestia_tpu.da.namespace import Namespace
-
-    return Blob(
-        Namespace.v0(b"smoke" + bytes([tag]) * 5),
-        rng.integers(0, 256, size, dtype=np.uint8).tobytes(),
-    )
-
-
 def fill_square(remote, signers, rng, k, n_tx):
     """Submit ``n_tx`` signed PayForBlobs (one per signer, concurrently,
     right after a block was committed, so they land in one block) whose
     blobs fill ~TRAFFIC_FILL of a k x k square; returns the heights they
     were committed at."""
+    from celestia_tpu.client.txsim import random_blob
+
     blob_bytes = int(TRAFFIC_FILL * k * k * 478) // n_tx
-    blobs = [make_blob(rng, i + 1, blob_bytes) for i in range(n_tx)]
+    blobs = [random_blob(rng, i, blob_bytes) for i in range(n_tx)]
     h0 = remote.height
     while remote.height == h0:
         time.sleep(0.01)
@@ -225,24 +226,15 @@ def run_one_chip(ks=(64, 128), platform="tpu", block_interval_s=2.0):
     # Uncommitted shapes, as the node passes its arrays: the same cache
     # key as the jitted calls that follow.
     codec = gf256.active_codec()
-
-    def shape(a, dtype=jnp.uint8):
-        return jax.ShapeDtypeStruct(a, dtype)
-
     warm = sorted({1, k_default // 2, k_default, k_cap})
     n2 = 2 * k_cap
     avail = np.random.default_rng(SEED + 1).random((n2, n2)) >= 0.25
-    rk, rm, _ck, _cm = rs._simulate_schedule(avail, k_cap)
     jobs = [
-        (f"extend_levels k={s}", device_plane._extend_levels_fn(s, codec), (shape((s, s, 512)),))
+        (f"extend_levels k={s}", device_plane._extend_levels_fn(s, codec),
+         (jax.ShapeDtypeStruct((s, s, 512), jnp.uint8),))
         for s in warm if s > 1
     ]
-    jobs.append((
-        f"repair_verify k={k_cap} phases={rk.shape[0]}",
-        rs._repair_verify_fn(k_cap, rk.shape[0], min(n2, max(1, 8192 // k_cap)), True, codec),
-        (shape((n2, n2, 512)), shape(avail.shape, jnp.bool_), shape(rk.shape),
-         shape(rm.shape, jnp.bool_), shape(rk.shape), shape(rm.shape, jnp.bool_)),
-    ))
+    jobs.append((f"repair_verify k={k_cap}", *rs.repair_program(avail)))
     timed("precompile (wall, programs in parallel)", precompile, jobs)
 
     # warm every size the run will extend BEFORE serving (cli start's
@@ -407,37 +399,12 @@ def check_device_path(platform):
 # ---------------------------------------------------------------------------
 
 
-def signed_block(app_node, keys, rng, k, tag):
-    """One block's txs filling ~TRAFFIC_FILL of a k x k square, signed by
-    ``keys`` at sequence 0, laid out by the square builder."""
-    from celestia_tpu.client.signer import Signer
-    from celestia_tpu.da.blob import BlobTx
-    from celestia_tpu.da.inclusion import create_commitment
-    from celestia_tpu.state.modules.blob import estimate_gas
-    from celestia_tpu.state.tx import MsgPayForBlobs
-
-    blob_bytes = int(TRAFFIC_FILL * k * k * 478) // len(keys)
-    txs = []
-    for i, key in enumerate(keys):
-        blob = make_blob(rng, tag * 16 + i + 1, blob_bytes)
-        signer = Signer(app_node, key)
-        msg = MsgPayForBlobs(
-            signer=signer.address,
-            namespaces=(blob.namespace.raw,),
-            blob_sizes=(len(blob.data),),
-            share_commitments=(create_commitment(blob),),
-            share_versions=(0,),
-        )
-        tx = signer.sign_tx([msg], gas_limit=estimate_gas([len(blob.data)]))
-        txs.append(BlobTx(tx.marshal(), (blob,)).marshal())
-    return txs
-
-
 def run_four_chips(k=128, batch=4, n_chips=4):
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+    from celestia_tpu.client.txsim import signed_pfb_txs
     from celestia_tpu.da import device_plane, eds_cache
     from celestia_tpu.da.square import construct as construct_square
     from celestia_tpu.node.testnode import TestNode
@@ -455,7 +422,11 @@ def run_four_chips(k=128, batch=4, n_chips=4):
     node.app.params.set("blob", "GovMaxSquareSize", k)
     blocks = []
     for b in range(batch + 1):
-        txs = signed_block(node, keys[4 * b : 4 * b + 4], rng, k, b)
+        # ~TRAFFIC_FILL of a k x k square, 4 fresh signers at sequence 0
+        txs = signed_pfb_txs(
+            node, keys[4 * b : 4 * b + 4], 4, int(TRAFFIC_FILL * k * k * 478) // 4,
+            rng, first_namespace=4 * b,
+        )
         square, block_txs, _w = construct_square(txs, k)
         check(square.size == k, f"block {b} built k={square.size}, want {k}")
         blocks.append((square, block_txs))

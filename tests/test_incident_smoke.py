@@ -14,7 +14,7 @@ import pytest
 
 from celestia_tpu.node.server import NodeService
 from celestia_tpu.node.testnode import TestNode
-from celestia_tpu.utils import faults, flight, hostprof, timeseries, tracing
+from celestia_tpu.utils import flight, hostprof, timeseries, tracing
 from celestia_tpu.utils.flight import FlightRecorder, validate_manifest
 from celestia_tpu.utils.telemetry import validate_exposition
 
@@ -36,9 +36,6 @@ def _stalled_service(tmp_path, with_flight=True):
     """A tiny-k node + NodeService with an injected fast stall rule and
     (optionally) a flight recorder — no gRPC server: the smoke's RPC
     handlers are bytes->bytes callables."""
-    # degradations are process-global: one recorded by an earlier module
-    # in this worker would fire the stock `degradations` rule here too
-    faults.reset_stats()
     node = TestNode(auto_produce=False)
     rec = (
         FlightRecorder(str(tmp_path / "flight"), min_interval_s=0.0)

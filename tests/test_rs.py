@@ -273,6 +273,41 @@ def test_repair_device_detects_byzantine():
         )
 
 
+def test_repair_program_is_the_program_repair_dispatches(monkeypatch):
+    """rs.repair_program hands an AOT caller (chip_smoke.py's precompile)
+    the very program, and argument shapes, that repair_square_device
+    then dispatches — so its compile lands on the same cache entry."""
+    from celestia_tpu.ops import nmt as nmt_ops
+
+    rng = np.random.default_rng(37)
+    k = 4
+    eds = np.asarray(
+        rs.extend_square(rng.integers(0, 256, (k, k, 512), dtype=np.uint8))
+    )
+    roots = np.asarray(nmt_ops.eds_nmt_roots(eds))
+    avail = rng.random((2 * k, 2 * k)) >= 0.25
+    fn, args = rs.repair_program(avail)
+    fn.lower(*args).compile()
+    seen = []
+    real = rs._repair_verify_fn
+
+    def spy(*key):
+        program = real(*key)
+
+        def call(*a):
+            seen.append((program, [(x.shape, x.dtype) for x in a]))
+            return program(*a)
+
+        return call
+
+    monkeypatch.setattr(rs, "_repair_verify_fn", spy)
+    repaired = rs.repair_square_device(
+        eds.copy(), avail, row_roots=roots[0], col_roots=roots[1]
+    )
+    assert np.array_equal(repaired, eds)
+    assert seen == [(fn, [(a.shape, a.dtype) for a in args])]
+
+
 def test_repair_device_insufficient_raises():
     k = 2
     square = np.zeros((k, k, 8), dtype=np.uint8)

@@ -18,6 +18,21 @@ from celestia_tpu.state.store import MultiStore
 from celestia_tpu.utils.secp256k1 import PrivateKey
 
 
+def test_signed_pfb_txs_pass_the_filter():
+    """txsim.signed_pfb_txs (bench.py, chip_smoke.py): round-robin
+    signers at consecutive sequences, one random blob each, every tx
+    kept by the proposer's FilterTxs."""
+    keys = [PrivateKey.from_seed(b"pfb-gen-%d" % i) for i in range(2)]
+    node = TestNode(
+        funded_accounts=[(key, 10**15) for key in keys], auto_produce=False
+    )
+    txs = txsim.signed_pfb_txs(
+        node, keys, 5, 1000, np.random.default_rng(3), first_namespace=7
+    )
+    assert len(set(txs)) == 5
+    assert node.app._filter_txs(txs) == txs
+
+
 def test_txsim_remote_blob_and_send():
     master = PrivateKey.from_seed(b"txsim-master")
     node = TestNode(

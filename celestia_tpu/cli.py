@@ -408,6 +408,15 @@ def cmd_start(args) -> int:
         )
         if devprof.start_profiler(device_profile_dir):
             log.info("device profiler capturing", dir=device_profile_dir)
+            # the program's spans reach the capture only while tracing
+            # is on: a profile asked for implies --trace
+            from celestia_tpu.utils import tracing
+
+            if not tracing.enabled():
+                tracing.enable()
+                log.info(
+                    "block tracing enabled", blocks=tracing.TRACER.max_blocks
+                )
         else:
             log.warn(
                 "device profiler unavailable on this platform; "
@@ -1615,7 +1624,8 @@ def build_parser() -> argparse.ArgumentParser:
         "--device-profile", nargs="?", const="", default=None, metavar="DIR",
         help="capture a jax.profiler (TensorBoard/XPlane) device trace "
              "into DIR (default: <home>/data/device-profile) for the "
-             "node's lifetime; degrades to a logged note without a "
+             "node's lifetime, with the block-trace spans as host events "
+             "(implies --trace); degrades to a logged note without a "
              "capturable device",
     )
     sp.add_argument(

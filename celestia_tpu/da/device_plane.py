@@ -217,32 +217,40 @@ def extend_and_header(square):
     codec = _active_codec()
     with tracing.span("extend.device_plane", k=k, codec=codec):
         fn = _extend_levels_fn(k, codec)
-        t0 = _clock()
-        arr = jnp.asarray(square)
-        # h2d charge: jnp.asarray ENQUEUES the upload — the recorded ms
-        # is scheduling cost, the wire time overlaps the dispatch below
-        devprof.record_transfer(
-            "extend_levels", "h2d", k * k * 512, (_clock() - t0) * 1000.0
-        )
+        with tracing.span("extend.upload", bytes=k * k * 512):
+            t0 = _clock()
+            arr = jnp.asarray(square)
+            # h2d charge: jnp.asarray ENQUEUES the upload — the recorded
+            # ms is scheduling cost, the wire time overlaps the dispatch
+            devprof.record_transfer(
+                "extend_levels", "h2d", k * k * 512, (_clock() - t0) * 1000.0
+            )
         d = devprof.dispatch("extend_levels", k=k, codec=codec)
-        eds_d, levels, root_levels = d.done(fn(arr))
-        # the ONE eager hot-path D2H: the 32-byte data root
-        data_root = bytes(devprof.fetch("data_root", root_levels[-1])[0])
+        with tracing.span("extend.launch"):
+            out = fn(arr)
+        # the host waits on the device here: the ONE eager hot-path D2H,
+        # the 32-byte data root
+        with tracing.span("extend.wait"):
+            eds_d, levels, root_levels = d.done(out)
+            data_root = bytes(devprof.fetch("data_root", root_levels[-1])[0])
         # axis roots, lazily fetched relative to the dispatch (they are
         # only needed to assemble the host DAH object) — ONE tuple fetch
-        axis_roots = devprof.fetch("roots", levels[-1])  # (2, 2k, 1, 90)
-        rr = axis_roots[0, :, 0, :]
-        cc = axis_roots[1, :, 0, :]
-        dah = DataAvailabilityHeader(
-            tuple(rr[i].tobytes() for i in range(rr.shape[0])),
-            tuple(cc[i].tobytes() for i in range(cc.shape[0])),
-            data_root,
-        )
+        roots_bytes = 4 * k * nmt_ops.NMT_DIGEST_SIZE
+        with tracing.span("extend.fetch_roots", bytes=roots_bytes):
+            axis_roots = devprof.fetch("roots", levels[-1])  # (2, 2k, 1, 90)
+        with tracing.span("extend.assemble"):
+            rr = axis_roots[0, :, 0, :]
+            cc = axis_roots[1, :, 0, :]
+            dah = DataAvailabilityHeader(
+                tuple(rr[i].tobytes() for i in range(rr.shape[0])),
+                tuple(cc[i].tobytes() for i in range(cc.shape[0])),
+                data_root,
+            )
+            entry = DevicePlaneEntry(k, data_root, eds_d, levels, root_levels)
+            eds_cache.put_device_entry(data_root, entry)
     # cost accounting OUTSIDE the traced span (da/dah.py placement
     # contract)
     devprof.note_compile("extend_levels", fn, (arr,))
-    entry = DevicePlaneEntry(k, data_root, eds_d, levels, root_levels)
-    eds_cache.put_device_entry(data_root, entry)
     return ExtendedDataSquare(eds_d), dah
 
 

@@ -42,28 +42,32 @@ _PARITY_NS = np.frombuffer(PARITY_SHARE_NAMESPACE_RAW, dtype=np.uint8)
 def leaf_digests(leaves: jnp.ndarray) -> jnp.ndarray:
     """Hash namespaced leaves: uint8[..., L] -> uint8[..., 90].
 
-    ``leaves`` already carry their namespace prefix (ns || data).
+    ``leaves`` already carry their namespace prefix (ns || data).  Ops
+    carry the ``nmt_leaves`` name scope.
     """
-    ns = leaves[..., :NAMESPACE_SIZE]
-    prefix = jnp.zeros(leaves.shape[:-1] + (1,), dtype=jnp.uint8)  # 0x00
-    h = sha256(jnp.concatenate([prefix, leaves], axis=-1))
-    return jnp.concatenate([ns, ns, h], axis=-1)
+    with jax.named_scope("nmt_leaves"):
+        ns = leaves[..., :NAMESPACE_SIZE]
+        prefix = jnp.zeros(leaves.shape[:-1] + (1,), dtype=jnp.uint8)  # 0x00
+        h = sha256(jnp.concatenate([prefix, leaves], axis=-1))
+        return jnp.concatenate([ns, ns, h], axis=-1)
 
 
 def combine_level(nodes: jnp.ndarray) -> jnp.ndarray:
-    """One reduction level: uint8[..., m, 90] -> uint8[..., m//2, 90]."""
-    left = nodes[..., 0::2, :]
-    right = nodes[..., 1::2, :]
-    l_min = left[..., :NAMESPACE_SIZE]
-    l_max = left[..., NAMESPACE_SIZE : 2 * NAMESPACE_SIZE]
-    r_min = right[..., :NAMESPACE_SIZE]
-    r_max = right[..., NAMESPACE_SIZE : 2 * NAMESPACE_SIZE]
-    parity = jnp.asarray(_PARITY_NS)
-    r_is_parity = jnp.all(r_min == parity, axis=-1, keepdims=True)  # IgnoreMaxNamespace
-    max_ns = jnp.where(r_is_parity, l_max, r_max)
-    prefix = jnp.ones(left.shape[:-1] + (1,), dtype=jnp.uint8)  # 0x01
-    h = sha256(jnp.concatenate([prefix, left, right], axis=-1))
-    return jnp.concatenate([l_min, max_ns, h], axis=-1)
+    """One reduction level: uint8[..., m, 90] -> uint8[..., m//2, 90].
+    Ops carry the ``nmt_inner`` name scope."""
+    with jax.named_scope("nmt_inner"):
+        left = nodes[..., 0::2, :]
+        right = nodes[..., 1::2, :]
+        l_min = left[..., :NAMESPACE_SIZE]
+        l_max = left[..., NAMESPACE_SIZE : 2 * NAMESPACE_SIZE]
+        r_min = right[..., :NAMESPACE_SIZE]
+        r_max = right[..., NAMESPACE_SIZE : 2 * NAMESPACE_SIZE]
+        parity = jnp.asarray(_PARITY_NS)
+        r_is_parity = jnp.all(r_min == parity, axis=-1, keepdims=True)  # IgnoreMaxNamespace
+        max_ns = jnp.where(r_is_parity, l_max, r_max)
+        prefix = jnp.ones(left.shape[:-1] + (1,), dtype=jnp.uint8)  # 0x01
+        h = sha256(jnp.concatenate([prefix, left, right], axis=-1))
+        return jnp.concatenate([l_min, max_ns, h], axis=-1)
 
 
 def nmt_roots(leaves: jnp.ndarray) -> jnp.ndarray:
@@ -291,15 +295,19 @@ def rfc6962_level_stack(leaves: jnp.ndarray) -> list:
     Traceable twin of da/proof.py's host ``merkle_level_tree`` (pinned
     byte-identical by tests/test_device_plane.py) — the device-resident
     plane keeps this stack on-chip so a data-root membership proof is a
-    gather of ``levels[j][(index >> j) ^ 1]``, never a re-hash.
+    gather of ``levels[j][(index >> j) ^ 1]``, never a re-hash.  Ops
+    carry the ``root_tree`` name scope.
     """
     n = leaves.shape[-2]
     if n & (n - 1):
         raise ValueError(f"leaf count must be a power of two, got {n}")
-    levels = [rfc6962_leaf_hashes(leaves)]
-    while levels[-1].shape[-2] > 1:
-        nodes = levels[-1]
-        levels.append(rfc6962_inner(nodes[..., 0::2, :], nodes[..., 1::2, :]))
+    with jax.named_scope("root_tree"):
+        levels = [rfc6962_leaf_hashes(leaves)]
+        while levels[-1].shape[-2] > 1:
+            nodes = levels[-1]
+            levels.append(
+                rfc6962_inner(nodes[..., 0::2, :], nodes[..., 1::2, :])
+            )
     return levels
 
 

@@ -62,14 +62,16 @@ def _row_parity(square: jnp.ndarray, G: jnp.ndarray) -> jnp.ndarray:
 
 
 def _extend(square: jnp.ndarray, G: jnp.ndarray) -> jnp.ndarray:
-    """Core extension: uint8[k, k, B] -> uint8[2k, 2k, B]."""
-    q0 = square
-    q1 = _row_parity(q0, G)  # row parity
-    q2 = _row_parity(q0.transpose(1, 0, 2), G).transpose(1, 0, 2)  # col parity
-    q3 = _row_parity(q1.transpose(1, 0, 2), G).transpose(1, 0, 2)  # parity of parity
-    top = jnp.concatenate([q0, q1], axis=1)
-    bottom = jnp.concatenate([q2, q3], axis=1)
-    return jnp.concatenate([top, bottom], axis=0)
+    """Core extension: uint8[k, k, B] -> uint8[2k, 2k, B].  Its ops carry
+    the ``rs`` name scope in the lowered program."""
+    with jax.named_scope("rs"):
+        q0 = square
+        q1 = _row_parity(q0, G)  # row parity
+        q2 = _row_parity(q0.transpose(1, 0, 2), G).transpose(1, 0, 2)  # col parity
+        q3 = _row_parity(q1.transpose(1, 0, 2), G).transpose(1, 0, 2)  # parity of parity
+        top = jnp.concatenate([q0, q1], axis=1)
+        bottom = jnp.concatenate([q2, q3], axis=1)
+        return jnp.concatenate([top, bottom], axis=0)
 
 
 @lru_cache(maxsize=None)

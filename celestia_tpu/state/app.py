@@ -564,41 +564,63 @@ class App:
         # crossings were a visible slice of FilterTxs
         parsed: List[tuple] = []  # (raw, key, btx_or_None, cache_hit)
         warm: List = []
-        for raw in txs:
-            key = _hashlib.sha256(raw).digest()
-            tx_keys.append(key)
-            hit = self._decoded_cache.get(key)
-            if hit is not None:
-                parsed.append((raw, key, None, hit))
-                continue
-            btx = unmarshal_blob_tx(raw)
-            if btx is not None:
-                warm.extend(btx.blobs)
-            parsed.append((raw, key, btx, None))
+        hits = 0
+        with tracing.span(
+            "ante.decode", stage="unmarshal", txs=len(txs), cpu=True
+        ) as sp:
+            for raw in txs:
+                key = _hashlib.sha256(raw).digest()
+                tx_keys.append(key)
+                hit = self._decoded_cache.get(key)
+                if hit is not None:
+                    parsed.append((raw, key, None, hit))
+                    hits += 1
+                    continue
+                btx = unmarshal_blob_tx(raw)
+                if btx is not None:
+                    warm.extend(btx.blobs)
+                parsed.append((raw, key, btx, None))
+            if tracing.enabled():
+                sp.annotate(
+                    bytes=sum(len(raw) for raw in txs), cache_hits=hits
+                )
         if warm:
             from celestia_tpu.da.inclusion import warm_commitments
 
-            warm_commitments(warm)
-        for raw, key, btx, hit in parsed:
-            if hit is not None:
-                decoded.append((raw, hit[0], hit[1], None))
-                continue
-            try:
-                if btx is not None:
-                    # full BlobTx validation incl. commitment recompute
-                    tx = validate_blob_tx(btx, self.chain_id)
-                    raw_inner = btx.tx
-                else:
-                    tx = unmarshal_tx(raw)
-                    from celestia_tpu.state.ante import flat_msgs
+            with tracing.span(
+                "ante.commitments", blobs=len(warm), cpu=True
+            ) as sp:
+                warm_commitments(warm)
+                if tracing.enabled():
+                    sp.annotate(
+                        blob_bytes=sum(len(b.data) for b in warm),
+                        shares=sum(b.shares_needed() for b in warm),
+                    )
+        with tracing.span(
+            "ante.decode", stage="validate", txs=len(txs) - hits, cpu=True
+        ):
+            for raw, key, btx, hit in parsed:
+                if hit is not None:
+                    decoded.append((raw, hit[0], hit[1], None))
+                    continue
+                try:
+                    if btx is not None:
+                        # full BlobTx validation incl. commitment recompute
+                        tx = validate_blob_tx(btx, self.chain_id)
+                        raw_inner = btx.tx
+                    else:
+                        tx = unmarshal_tx(raw)
+                        from celestia_tpu.state.ante import flat_msgs
 
-                    if any(isinstance(m, MsgPayForBlobs) for m in flat_msgs(tx)):
-                        raise AnteError("PFB without blobs")
-                    raw_inner = raw
-                decoded.append((raw, tx, raw_inner, None))
-                self._remember_decoded(key, tx, raw_inner)
-            except (AnteError, ValueError) as e:
-                decoded.append((raw, None, None, e))
+                        if any(
+                            isinstance(m, MsgPayForBlobs) for m in flat_msgs(tx)
+                        ):
+                            raise AnteError("PFB without blobs")
+                        raw_inner = raw
+                    decoded.append((raw, tx, raw_inner, None))
+                    self._remember_decoded(key, tx, raw_inner)
+                except (AnteError, ValueError) as e:
+                    decoded.append((raw, None, None, e))
         # single-key txs batch-verify natively; multisig txs fall back to
         # inline verification inside the ante chain (sig_ok=None).
         # batch_ok is THIS call's key -> verdict map: cache hits resolve
@@ -609,28 +631,32 @@ class App:
         keys: List[Optional[bytes]] = []
         live: List[tuple] = []
         live_keys: List[bytes] = []
-        for d, key in zip(decoded, tx_keys):
-            if d[1] is None or d[1].is_multisig():
-                keys.append(None)
-                continue
-            keys.append(key)
-            if key in batch_ok:
-                continue
-            if self._sig_cache.get(key) is not None:
-                batch_ok[key] = True
-            else:
-                batch_ok[key] = None  # to be verified below
-                live.append(d)
-                live_keys.append(key)
-        sig_results = verify_batch(
-            [tx.sign_bytes(self.chain_id) for _, tx, _, _ in live],
-            [tx.signature for _, tx, _, _ in live],
-            [tx.pubkey for _, tx, _, _ in live],
-        )
-        for key, ok in zip(live_keys, sig_results):
-            batch_ok[key] = bool(ok)
-            if ok:
-                self._remember_sig(key)
+        with tracing.span("ante.sig_verify", cpu=True) as sp:
+            for d, key in zip(decoded, tx_keys):
+                if d[1] is None or d[1].is_multisig():
+                    keys.append(None)
+                    continue
+                keys.append(key)
+                if key in batch_ok:
+                    continue
+                if self._sig_cache.get(key) is not None:
+                    batch_ok[key] = True
+                else:
+                    batch_ok[key] = None  # to be verified below
+                    live.append(d)
+                    live_keys.append(key)
+            sig_results = verify_batch(
+                [tx.sign_bytes(self.chain_id) for _, tx, _, _ in live],
+                [tx.signature for _, tx, _, _ in live],
+                [tx.pubkey for _, tx, _, _ in live],
+            )
+            for key, ok in zip(live_keys, sig_results):
+                batch_ok[key] = bool(ok)
+                if ok:
+                    self._remember_sig(key)
+            sp.annotate(
+                verified=len(live), cache_hits=len(batch_ok) - len(live)
+            )
         out = []
         for d, key in zip(decoded, keys):
             raw, tx, raw_inner, err = d
@@ -889,19 +915,22 @@ class App:
         from celestia_tpu.da import eds_cache
         from celestia_tpu.ops import gf256 as _gf256
 
-        key = eds_cache.make_key(
-            block_txs, square.size, self.app_version, _gf256.active_codec()
-        )
+        # the key hashes every tx byte again: a sibling of ``extend``, so
+        # extend_ms stays the lookup and the extend alone
+        with tracing.span("eds_cache.key", leg=leg) as sp:
+            key = eds_cache.make_key(
+                block_txs, square.size, self.app_version, _gf256.active_codec()
+            )
+            if tracing.enabled():
+                sp.annotate(bytes=sum(len(raw) for raw in block_txs))
         with tracing.span("extend", leg=leg, k=square.size) as sp:
             cached = eds_cache.get(key)
             if cached is not None:
                 self.telemetry.incr(f"eds_cache_hit_{leg}")
                 sp.annotate(eds_cache="hit")
-                tracing.instant("eds_cache.hit", cat="cache", leg=leg)
                 return cached
             self.telemetry.incr(f"eds_cache_miss_{leg}")
             sp.annotate(eds_cache="miss")
-            tracing.instant("eds_cache.miss", cat="cache", leg=leg)
             eds, dah = self._extend_square_routed(square)
             eds_cache.put(key, eds, dah)
             return eds, dah
@@ -1160,26 +1189,26 @@ class App:
         accounts = AccountKeeper(branch.store("auth"))
         bank = BankKeeper(branch.store("bank"))
         params = ParamsKeeper(branch.store("params"))
-        with tracing.span("decode_and_ante", txs=len(block_txs)):
-            for raw, tx, raw_inner, sig_ok, err in self._decode_proposal_txs(
-                block_txs
-            ):
-                if err is not None:
-                    return False, f"invalid tx in proposal: {err}"
-                ctx = AnteContext(
-                    tx=tx,
-                    raw_tx=raw_inner,
-                    accounts=accounts,
-                    bank=bank,
-                    params=params,
-                    chain_id=self.chain_id,
-                    app_version=self.app_version,
-                    sig_ok=sig_ok,
-                    height=self.next_height(),
-                    feegrant=FeeGrantKeeper(branch.store("feegrant")),
-                    time_ns=self.block_time_ns,
-                )
-                run_ante(ctx)
+        with tracing.span("decode_and_ante", txs=len(block_txs), cpu=True):
+            decoded = self._decode_proposal_txs(block_txs)
+            with tracing.span("ante.chain", txs=len(decoded), cpu=True):
+                for raw, tx, raw_inner, sig_ok, err in decoded:
+                    if err is not None:
+                        return False, f"invalid tx in proposal: {err}"
+                    ctx = AnteContext(
+                        tx=tx,
+                        raw_tx=raw_inner,
+                        accounts=accounts,
+                        bank=bank,
+                        params=params,
+                        chain_id=self.chain_id,
+                        app_version=self.app_version,
+                        sig_ok=sig_ok,
+                        height=self.next_height(),
+                        feegrant=FeeGrantKeeper(branch.store("feegrant")),
+                        time_ns=self.block_time_ns,
+                    )
+                    run_ante(ctx)
         # strict reconstruction — NOT skippable on a cache hit: the
         # square must be re-derivable from the tx bytes under the
         # CURRENT size bound, and only that reconstruction makes the

@@ -32,7 +32,14 @@ Design constraints, in order:
 
 Clock: durations are measured through :func:`telemetry.clock` — the one
 sanctioned wall-clock channel (celint R3) — and only ever feed
-telemetry/trace output, never consensus bytes.
+telemetry/trace output, never consensus bytes.  While tracing is on,
+every context-manager span also enters a ``jax.profiler.TraceAnnotation``
+of its name, so a profiler capture (``--device-profile``,
+``devprof.start_profiler``) holds the program's spans as host events on
+the profiler's own clock, beside the device ops.  A span opened with
+``cpu=True`` also records ``cpu_ms``, the process CPU time (all threads)
+it covered, and Python's collector pauses become ``gc`` spans under the
+span that was running (see :meth:`Tracer.enable`).
 
 Cross-node tracing (PR 9): the workload is inherently multi-node — one
 block's causal chain is the proposer's prepare, every validator's
@@ -57,10 +64,13 @@ This module therefore also carries:
 from __future__ import annotations
 
 import contextvars
+import gc
 import itertools
 import json
+import sys
 import threading
 from collections import deque
+from time import process_time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from celestia_tpu.utils.telemetry import Log2Histogram, clock
@@ -72,6 +82,9 @@ ENV_NODE_ID = "CELESTIA_TPU_NODE_ID"
 DEFAULT_MAX_BLOCKS = 8
 MAX_SPANS_PER_BLOCK = 8192
 MAX_BACKGROUND_SPANS = 2048
+
+# the category of the collector-pause spans (Tracer.enable)
+GC_CAT = "gc"
 
 # ---------------------------------------------------------------------------
 # node identity (cross-node attribution)
@@ -125,7 +138,7 @@ class Span:
 
     __slots__ = (
         "span_id", "parent_id", "name", "cat", "t0", "t1", "tid",
-        "thread_name", "args", "_sink", "_token",
+        "thread_name", "args", "_sink", "_token", "cpu0",
     )
 
     def __init__(self, name, cat, parent_id, sink, args, t0=None, t1=0.0):
@@ -144,6 +157,8 @@ class Span:
         self.thread_name = t.name
         self.t0 = clock() if t0 is None else t0
         self.t1 = t1
+        # process CPU seconds at open, for spans that record cpu_ms
+        self.cpu0: Optional[float] = None
 
     @property
     def duration_ms(self) -> float:
@@ -253,10 +268,13 @@ class BlockTrace:
         """Structural form (no durations, no ids): {name, children}
         nested by parent links — what the determinism tests compare.
         Children are sorted by name: pool workers finish in arbitrary
-        order, and completion order is timing, not structure."""
-        by_id = {s.span_id: {"name": s.name, "children": []} for s in self.spans}
+        order, and completion order is timing, not structure.  ``gc``
+        spans are left out: a collection lands wherever allocation
+        crossed a threshold, which is timing too."""
+        spans = self.structural_spans()
+        by_id = {s.span_id: {"name": s.name, "children": []} for s in spans}
         root = None
-        for s in self.spans:
+        for s in spans:
             node = by_id[s.span_id]
             parent = by_id.get(s.parent_id)
             if parent is not None:
@@ -266,6 +284,10 @@ class BlockTrace:
         for node in by_id.values():
             node["children"].sort(key=lambda n: n["name"])
         return root or {"name": self.name, "children": []}
+
+    def structural_spans(self) -> List[Span]:
+        """The spans the program opened, without the collector's."""
+        return [s for s in self.spans if s.cat != GC_CAT]
 
 
 class Tracer:
@@ -288,31 +310,64 @@ class Tracer:
         # truncating must be detectable remotely long after);
         # celint: guarded-by(self._lock)
         self._span_drops_total = 0
+        # gc spans waiting to be placed: the collector's callback may run
+        # while this very thread holds self._lock, so it never takes the
+        # lock; _finish places them (deque appends are atomic)
+        self._gc_pending: "deque[Span]" = deque()
+        self._gc_t0 = 0.0
         self.enabled = False
 
     # -- lifecycle -----------------------------------------------------
 
     def enable(self, max_blocks: Optional[int] = None) -> None:
+        """Turn tracing on.  Only while it is on, a ``gc.callbacks`` hook
+        records every collection as a ``gc`` span (args ``generation``,
+        ``collected``) under the span running on the collecting thread."""
         global _enabled
         with self._lock:
             if max_blocks is not None and max_blocks != self._blocks.maxlen:
                 self._blocks = deque(self._blocks, maxlen=max(1, max_blocks))
             self.enabled = True
+            if self._on_gc not in gc.callbacks:
+                gc.callbacks.append(self._on_gc)
         _enabled = True
 
     def disable(self) -> None:
         global _enabled
         with self._lock:
             self.enabled = False
+            while self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
         _enabled = False
+
+    def gc_hook_installed(self) -> bool:
+        return self._on_gc in gc.callbacks
 
     def clear(self) -> None:
         with self._lock:
             self._blocks.clear()
             self._background.clear()
             self._agg.clear()
+            self._gc_pending.clear()
             self._span_drops_total = 0
         _active_by_thread.clear()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # collections are serialized process-wide and do not nest (the
+        # collector cannot re-enter from its own callbacks), so one start
+        # stamp is enough
+        if phase == "start":
+            self._gc_t0 = clock()
+            return
+        parent = _current.get()
+        if parent is None or not self.enabled:
+            return
+        self._gc_pending.append(Span(
+            "gc", GC_CAT, parent.span_id, parent._sink,
+            {"generation": info.get("generation"),
+             "collected": info.get("collected")},
+            t0=self._gc_t0, t1=clock(),
+        ))
 
     @property
     def max_blocks(self) -> int:
@@ -325,17 +380,21 @@ class Tracer:
         name: str,
         cat: str = "block",
         parent: Optional[Span] = None,
+        cpu: bool = False,
         **args,
     ):
         """Context manager for one timed operation, parented to the
         current contextvar span (or an explicit ``parent`` — the
-        cross-thread form pool workers use)."""
+        cross-thread form pool workers use).  ``cpu=True`` records the
+        process CPU time across the span as arg ``cpu_ms``."""
         if not self.enabled:
             return NULL_SPAN
         if parent is None:
             parent = _current.get()
         sink = parent._sink if isinstance(parent, Span) else None
         s = Span(name, cat, parent.span_id if parent else 0, sink, args)
+        if cpu:
+            s.cpu0 = process_time()
         return _SpanCtx(self, s)
 
     def block_span(self, name: str, height: int, **args):
@@ -442,34 +501,44 @@ class Tracer:
 
     def _finish(self, s: Span) -> None:
         with self._lock:
-            hist = self._agg.get(s.name)
-            if hist is None:
-                hist = self._agg[s.name] = Log2Histogram()
-            hist.observe(max(0.0, s.t1 - s.t0))
-            sink = s._sink
-            if sink is not None:
-                is_root = s.span_id == sink.root_id
-                # the ROOT is exempt from the cap: it finishes last, and
-                # dropping it would turn an over-full trace into an
-                # empty tree (no parent for anything) instead of a
-                # truncated-but-readable one
-                if is_root or (
-                    len(sink.spans) + len(sink.instants) < MAX_SPANS_PER_BLOCK
-                ):
-                    sink.spans.append(s)
-                else:
-                    sink.dropped += 1
-                    self._span_drops_total += 1
-                if is_root:
-                    sink.complete = True
-                    self._blocks.append(sink)
+            self._place_gc_locked()
+            self._place_locked(s)
+
+    def _place_gc_locked(self) -> None:
+        pending = self._gc_pending
+        while pending:
+            self._place_locked(pending.popleft())
+
+    def _place_locked(self, s: Span) -> None:
+        hist = self._agg.get(s.name)
+        if hist is None:
+            hist = self._agg[s.name] = Log2Histogram()
+        hist.observe(max(0.0, s.t1 - s.t0))
+        sink = s._sink
+        if sink is not None:
+            is_root = s.span_id == sink.root_id
+            # the ROOT is exempt from the cap: it finishes last, and
+            # dropping it would turn an over-full trace into an
+            # empty tree (no parent for anything) instead of a
+            # truncated-but-readable one
+            if is_root or (
+                len(sink.spans) + len(sink.instants) < MAX_SPANS_PER_BLOCK
+            ):
+                sink.spans.append(s)
             else:
-                self._background.extend(s.export_events())
+                sink.dropped += 1
+                self._span_drops_total += 1
+            if is_root:
+                sink.complete = True
+                self._blocks.append(sink)
+        else:
+            self._background.extend(s.export_events())
 
     # -- export --------------------------------------------------------
 
     def block_traces(self, last: Optional[int] = None) -> List[BlockTrace]:
         with self._lock:
+            self._place_gc_locked()
             traces = list(self._blocks)
         if last is not None:
             traces = traces[-max(0, int(last)):]
@@ -612,22 +681,31 @@ class _SpanCtx:
     span object that is created but never entered (held in a variable,
     discarded on a branch) must not corrupt the thread's parent chain."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
     def __init__(self, tracer: Tracer, span: Span):
         self._tracer = tracer
         self._span = span
+        self._annotation = None
 
     def __enter__(self) -> Span:
         self._span._token = _current.set(self._span)
         _active_by_thread[self._span.tid] = self._span
+        ann = _profiler_annotation(self._span.name)
+        if ann is not None:
+            ann.__enter__()
+            self._annotation = ann
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         s = self._span
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         if exc is not None:
             s.args["error"] = repr(exc)[:200]
         s.t1 = clock()
+        if s.cpu0 is not None:
+            s.args["cpu_ms"] = (process_time() - s.cpu0) * 1000.0
         if s._token is not None:
             # restore the thread's sampler-visible span to whatever was
             # active before this one (the token records the old value)
@@ -641,6 +719,28 @@ class _SpanCtx:
             _current.reset(s._token)
         self._tracer._finish(s)
         return False
+
+
+# jax.profiler.TraceAnnotation once resolved; False where it cannot be had
+_annotation_cls: Any = None
+
+
+def _profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``, or None.
+
+    jax is never imported from here: a process that has not loaded it has
+    no profiler to capture into, and gets no annotation until it does."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except Exception:
+            cls = False
+        _annotation_cls = cls
+    return cls(name) if cls else None
 
 
 # ---------------------------------------------------------------------------
@@ -670,10 +770,19 @@ def clear() -> None:
     TRACER.clear()
 
 
-def span(name: str, cat: str = "block", parent: Optional[Span] = None, **args):
+def gc_hook_installed() -> bool:
+    """True while the collector hook is registered (only when tracing is
+    on)."""
+    return TRACER.gc_hook_installed()
+
+
+def span(
+    name: str, cat: str = "block", parent: Optional[Span] = None,
+    cpu: bool = False, **args,
+):
     if not _enabled:
         return NULL_SPAN
-    return TRACER.span(name, cat=cat, parent=parent, **args)
+    return TRACER.span(name, cat=cat, parent=parent, cpu=cpu, **args)
 
 
 def block_span(name: str, height: int, **args):

@@ -30,6 +30,7 @@ from celestia_tpu.appconsts import (
 )
 from celestia_tpu.da import dah as dah_mod
 from celestia_tpu.da.blob import BlobTx, unmarshal_blob_tx
+from celestia_tpu.da.inclusion import warm_commitments
 from celestia_tpu.da.square import Square, build as build_square, construct as construct_square
 from celestia_tpu.state import app_versions
 from celestia_tpu.state.ante import AnteContext, AnteError, GasMeter, run_ante
@@ -336,7 +337,10 @@ class App:
                 if is_recheck:
                     tx = unmarshal_tx(btx.tx)
                 else:
-                    tx = validate_blob_tx(btx, self.chain_id)
+                    warm_commitments([(key, btx.blobs)])
+                    tx = validate_blob_tx(
+                        btx, self.chain_id, key, self.telemetry
+                    )
                     # the verdict is deterministic in the raw bytes:
                     # Prepare/Process reuse it instead of re-hashing the
                     # blob payloads (check_tx.go validates, then the
@@ -428,11 +432,9 @@ class App:
                     continue
                 btx = unmarshal_blob_tx(raw)
                 if btx is not None and not is_recheck:
-                    warm.extend(btx.blobs)
+                    warm.append((key, btx.blobs))
                 parsed.append((raw, key, btx, None))
             if warm:
-                from celestia_tpu.da.inclusion import warm_commitments
-
                 warm_commitments(warm)
             decoded: List[tuple] = []  # (tx, raw_inner, err)
             for raw, key, btx, hit in parsed:
@@ -444,7 +446,9 @@ class App:
                         if is_recheck:
                             tx = unmarshal_tx(btx.tx)
                         else:
-                            tx = validate_blob_tx(btx, self.chain_id)
+                            tx = validate_blob_tx(
+                                btx, self.chain_id, key, self.telemetry
+                            )
                             self._remember_decoded(key, tx, btx.tx)
                         raw_inner = btx.tx
                     else:
@@ -558,12 +562,11 @@ class App:
         # for blob txs).
         decoded: List[tuple] = []
         tx_keys: List[bytes] = []
-        # pass 1: unmarshal envelopes and batch-warm every blob commitment
-        # in ONE native call (per-blob recompute inside validate_blob_tx
-        # then hits the cache) — at proposal scale the per-blob native
-        # crossings were a visible slice of FilterTxs
+        # pass 1: unmarshal envelopes, then compute every blob commitment
+        # in ONE native pass, keyed by the tx digest taken here (the
+        # lookups inside validate_blob_tx then hit without hashing a blob)
         parsed: List[tuple] = []  # (raw, key, btx_or_None, cache_hit)
-        warm: List = []
+        warm: List[tuple] = []  # (tx digest, blobs)
         hits = 0
         with tracing.span(
             "ante.decode", stage="unmarshal", txs=len(txs), cpu=True
@@ -578,23 +581,25 @@ class App:
                     continue
                 btx = unmarshal_blob_tx(raw)
                 if btx is not None:
-                    warm.extend(btx.blobs)
+                    warm.append((key, btx.blobs))
                 parsed.append((raw, key, btx, None))
             if tracing.enabled():
                 sp.annotate(
                     bytes=sum(len(raw) for raw in txs), cache_hits=hits
                 )
         if warm:
-            from celestia_tpu.da.inclusion import warm_commitments
-
             with tracing.span(
-                "ante.commitments", blobs=len(warm), cpu=True
+                "ante.commitments",
+                blobs=sum(len(blobs) for _, blobs in warm),
+                cpu=True,
             ) as sp:
-                warm_commitments(warm)
+                tasks = warm_commitments(warm)
                 if tracing.enabled():
+                    blobs = [b for _, tx_blobs in warm for b in tx_blobs]
                     sp.annotate(
-                        blob_bytes=sum(len(b.data) for b in warm),
-                        shares=sum(b.shares_needed() for b in warm),
+                        blob_bytes=sum(len(b.data) for b in blobs),
+                        shares=sum(b.shares_needed() for b in blobs),
+                        tasks=tasks,
                     )
         with tracing.span(
             "ante.decode", stage="validate", txs=len(txs) - hits, cpu=True
@@ -606,7 +611,9 @@ class App:
                 try:
                     if btx is not None:
                         # full BlobTx validation incl. commitment recompute
-                        tx = validate_blob_tx(btx, self.chain_id)
+                        tx = validate_blob_tx(
+                            btx, self.chain_id, key, self.telemetry
+                        )
                         raw_inner = btx.tx
                     else:
                         tx = unmarshal_tx(raw)

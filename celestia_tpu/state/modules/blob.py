@@ -19,7 +19,7 @@ from celestia_tpu.appconsts import (
     SUPPORTED_SHARE_VERSIONS,
 )
 from celestia_tpu.da.blob import BlobTx
-from celestia_tpu.da.inclusion import create_commitment
+from celestia_tpu.da.inclusion import tx_commitment
 from celestia_tpu.da.namespace import Namespace
 from celestia_tpu.da.shares import sparse_shares_needed
 from celestia_tpu.state.params import ParamsKeeper
@@ -63,10 +63,18 @@ def validate_msg_pay_for_blobs(msg: MsgPayForBlobs) -> None:
             raise ValueError(f"unsupported share version {ver}")
 
 
-def validate_blob_tx(blob_tx: BlobTx, chain_id: str) -> Tx:
+def validate_blob_tx(
+    blob_tx: BlobTx, chain_id: str, tx_digest: bytes, telemetry
+) -> Tx:
     """Full BlobTx validation (types/blob_tx.go:37-110): the wrapped tx must
     contain exactly one MsgPayForBlobs whose namespaces, sizes, versions and
     share commitments match the attached blobs (commitments recomputed).
+
+    ``tx_digest``, SHA-256 of the raw bytes ``blob_tx`` was unmarshalled
+    from, keys the commitments (``inclusion.tx_commitment``): a warm pass
+    over the same digest has computed them, and one it left out is
+    computed here and counted as ``commitment_warm_miss`` on
+    ``telemetry``.
 
     Returns the decoded inner Tx on success.
     """
@@ -87,7 +95,10 @@ def validate_blob_tx(blob_tx: BlobTx, chain_id: str) -> Tx:
             raise ValueError(f"blob {i}: size mismatch with PFB")
         if b.share_version != msg.share_versions[i]:
             raise ValueError(f"blob {i}: share version mismatch with PFB")
-        if create_commitment(b) != msg.share_commitments[i]:
+        commitment, missed = tx_commitment(tx_digest, i, b)
+        if missed:
+            telemetry.incr("commitment_warm_miss")
+        if commitment != msg.share_commitments[i]:
             raise ValueError(f"blob {i}: share commitment mismatch")
     return tx
 

@@ -15,7 +15,7 @@ import os
 import subprocess
 import threading
 from pathlib import Path
-from typing import Optional
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -149,17 +149,13 @@ def _load() -> Optional[ctypes.CDLL]:
     u8p = ctypes.POINTER(ctypes.c_uint8)
     lib.rs_extend_square.argtypes = [u8p, u8p, u8p, ctypes.c_int, ctypes.c_int]
     lib.sha256_batch.argtypes = [u8p, ctypes.c_int, ctypes.c_int, u8p]
-    lib.nmt_root.argtypes = [u8p, ctypes.c_int, ctypes.c_int, u8p]
-    lib.create_commitment.argtypes = [
-        u8p, ctypes.c_int, ctypes.c_int,
-        ctypes.POINTER(ctypes.c_int32), ctypes.c_int, u8p,
-    ]
     i32p = ctypes.POINTER(ctypes.c_int32)
     try:
-        lib.create_commitments_batch.argtypes = [
-            u8p, ctypes.c_int, i32p, i32p, i32p, ctypes.c_int, u8p,
-            ctypes.c_int,
+        lib.blob_commitments.argtypes = [
+            ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_int64),
+            u8p, u8p, ctypes.c_int, i32p, i32p, u8p, ctypes.c_int,
         ]
+        lib.blob_commitments.restype = ctypes.c_int
     except AttributeError:
         return None  # stale .so predating this round: see codec guard below
     lib.eds_nmt_roots.argtypes = [u8p, ctypes.c_int, ctypes.c_int, u8p]
@@ -440,67 +436,39 @@ def extend_block_leopard_cpu(
     return eds, roots, data_root
 
 
-def nmt_root(leaves: np.ndarray) -> np.ndarray:
-    """Root of one NMT whose leaves are ns-prefixed payloads.
+def blob_commitments(
+    datas: Sequence[bytes], namespaces: bytes, versions: bytes,
+    sizes: np.ndarray, size_off: np.ndarray, nthreads: Optional[int] = None,
+) -> Tuple[np.ndarray, int]:
+    """Share commitments of many blobs in one call, from their bytes.
 
-    leaves: uint8[n, leaf_len] with n a power of two -> uint8[90].
-    """
+    Blob i is ``datas[i]`` (non-empty, under 2**32 bytes) with namespace
+    ``namespaces[29 i : 29 (i + 1)]`` and share version ``versions[i]``;
+    its merkle-mountain-range widths are ``sizes[size_off[i] :
+    size_off[i + 1]]``.  The C side reads each blob in place (no copy
+    here), lays every share out as it hashes it, and runs its tasks (a
+    mountain of a large blob, or a run of small blobs) on the pool's
+    parked threads.  Returns (uint8[n, 32], tasks)."""
     lib = _load()
     if lib is None:
         raise RuntimeError("native library unavailable")
-    leaves = np.ascontiguousarray(leaves, dtype=np.uint8)
-    n, leaf_len = leaves.shape
-    out = np.zeros(90, dtype=np.uint8)
-    lib.nmt_root(_ptr(leaves), n, leaf_len, _ptr(out))
-    return out
-
-
-def create_commitment(leaves: np.ndarray, sizes) -> bytes:
-    """Blob share commitment in ONE native call: NMT roots of the
-    mountain-range subtrees + the RFC-6962 root over them.
-
-    leaves: uint8[n, leaf_len] ns-prefixed shares; sizes: mountain widths
-    summing to n.  Replaces ~one ctypes crossing per subtree."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
-    leaves = np.ascontiguousarray(leaves, dtype=np.uint8)
-    n, leaf_len = leaves.shape
-    sizes_arr = np.ascontiguousarray(sizes, dtype=np.int32)
-    out = np.zeros(32, dtype=np.uint8)
-    lib.create_commitment(
-        _ptr(leaves), n, leaf_len,
-        sizes_arr.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        len(sizes_arr), _ptr(out),
-    )
-    return out.tobytes()
-
-
-def create_commitments_batch(
-    leaves: np.ndarray, blob_off: np.ndarray, sizes: np.ndarray,
-    size_off: np.ndarray, nthreads: Optional[int] = None,
-) -> np.ndarray:
-    """Commitments for MANY blobs in one call: leaves uint8[total, leaf_len]
-    (all blobs' ns-prefixed shares concatenated), blob_off int32[n+1] row
-    offsets, sizes int32[...] mountain widths (concatenated), size_off
-    int32[n+1] offsets into sizes.  Returns uint8[n, 32]."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
-    leaves = np.ascontiguousarray(leaves, dtype=np.uint8)
-    blob_off = np.ascontiguousarray(blob_off, dtype=np.int32)
+    n = len(datas)
+    ptrs = (ctypes.c_char_p * n)(*datas)
+    lens = np.fromiter((len(d) for d in datas), dtype=np.int64, count=n)
+    ns = np.frombuffer(namespaces, dtype=np.uint8)
+    ver = np.frombuffer(versions, dtype=np.uint8)
+    if ns.size != 29 * n or ver.size != n:
+        raise ValueError("one 29-byte namespace and one version per blob")
     sizes = np.ascontiguousarray(sizes, dtype=np.int32)
     size_off = np.ascontiguousarray(size_off, dtype=np.int32)
-    n = len(blob_off) - 1
     out = np.zeros((n, 32), dtype=np.uint8)
     i32 = ctypes.POINTER(ctypes.c_int32)
-    lib.create_commitments_batch(
-        _ptr(leaves), leaves.shape[1],
-        blob_off.ctypes.data_as(i32), sizes.ctypes.data_as(i32),
-        size_off.ctypes.data_as(i32), n, _ptr(out),
-        _resolve_threads(nthreads),
+    tasks = lib.blob_commitments(
+        ptrs, lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        _ptr(ns), _ptr(ver), n, sizes.ctypes.data_as(i32),
+        size_off.ctypes.data_as(i32), _ptr(out), _resolve_threads(nthreads),
     )
-    return out
+    return out, tasks
 
 
 def gf_matmul_axes(

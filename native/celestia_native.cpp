@@ -15,12 +15,17 @@
 
 #include <cstdint>
 #include <cstring>
+#include <pthread.h>
 #if defined(__AVX2__)
 #include <immintrin.h>
 #endif
 
+#include <algorithm>
 #include <array>
 #include <atomic>
+#include <condition_variable>
+#include <memory>
+#include <mutex>
 #include <thread>
 #include <vector>
 
@@ -352,65 +357,198 @@ static void rfc6962_rec(const uint8_t* leaves, int n, int leaf_len,
     sha256_one(lr, 65, out32);
 }
 
-// Blob share commitment (go-square/inclusion.CreateCommitment role): the
-// RFC-6962 root over the NMT roots of the blob's merkle-mountain-range
-// subtrees.  leaves: n x leaf_len ns-prefixed shares (contiguous); sizes:
-// m mountain widths summing to n.  One call replaces one ctypes crossing
-// PER SUBTREE (~62/blob) — the host cost that dominated commitment
-// recompute in PrepareProposal/ProcessProposal.
-void create_commitment(const uint8_t* leaves, int n, int leaf_len,
-                       const int32_t* sizes, int m, uint8_t* out32) {
-    (void)n;
-    uint8_t* roots = new uint8_t[(size_t)m * DIGEST];
-    size_t off = 0;
-    for (int i = 0; i < m; i++) {
-        nmt_root(leaves + off * leaf_len, sizes[i], leaf_len,
-                 roots + (size_t)i * DIGEST);
-        off += (size_t)sizes[i];
+// ---------------------------------------------------------------------------
+// Blob share commitments straight from the blob bytes (go-square/inclusion
+// CreateCommitment role): per blob, the RFC-6962 root over the NMT roots
+// of its merkle-mountain-range subtrees.
+// ---------------------------------------------------------------------------
+
+// Sparse-share layout, as da/shares.py blob_shares_array writes it: the
+// namespace, the info byte (version << 1 | sequence start), the big-endian
+// sequence length on the first share only, then the blob's bytes in 478-
+// (first) or 482-byte (continuation) chunks, zero-padded to SHARE.
+static const int SHARE = 512;
+static const int FIRST_CONTENT = SHARE - NS - 1 - 4;  // 478
+static const int CONT_CONTENT = SHARE - NS - 1;       // 482
+// A helper below this many leaves (~20 us of hashing) costs about as
+// much to wake as it saves, so a call runs at most one thread per that
+// many leaves.
+static const int LEAVES_PER_THREAD = 32;
+// Tasks per thread the grain aims for: enough for the queue to even out
+// tasks of unequal size, few enough that the queue costs nothing.
+static const int TASKS_PER_THREAD = 8;
+
+struct CommitCtx {
+    const uint8_t* const* data;  // per blob: its bytes
+    const int64_t* lens;         // per blob: its length
+    const uint8_t* ns;           // per blob: its namespace (NS bytes)
+    const uint8_t* versions;     // per blob: its share version
+    const int32_t* size_off;     // blob b's mountains: [size_off[b], size_off[b + 1])
+    std::vector<int32_t> blob;   // per mountain: its blob
+    std::vector<int64_t> first;  // per mountain: its first share in the blob
+    std::vector<int32_t> width;  // per mountain: its leaf count
+    std::vector<int32_t> task_begin, task_end;  // per task: its mountains
+    std::vector<int32_t> order;  // dispatch order: tasks with most leaves first
+    std::unique_ptr<std::atomic<int>[]> pending;  // per blob: mountains left
+    std::vector<uint8_t> roots;  // per mountain: its NMT root
+    uint8_t* out;                // per blob: its commitment
+};
+
+// NMT leaf digest of share j of blob b: ns || ns || sha256(0x00 || ns ||
+// share), the share laid out in a stack buffer (the Q0 namespace-prefix
+// rule: a blob share is prefixed with its own namespace).
+static void blob_leaf(const CommitCtx& c, int b, int64_t j, uint8_t* out) {
+    const uint8_t* ns = c.ns + (size_t)b * NS;
+    const int64_t len = c.lens[b];
+    uint8_t msg[1 + NS + SHARE];
+    uint8_t* share = msg + 1 + NS;
+    msg[0] = 0x00;
+    memcpy(msg + 1, ns, NS);
+    memcpy(share, ns, NS);
+    share[NS] = (uint8_t)((c.versions[b] << 1) | (j == 0 ? 1 : 0));
+    uint8_t* content;
+    int64_t off;
+    int cap;
+    if (j == 0) {
+        for (int i = 0; i < 4; i++)
+            share[NS + 1 + i] = (uint8_t)(len >> (8 * (3 - i)));
+        content = share + NS + 5;
+        off = 0;
+        cap = FIRST_CONTENT;
+    } else {
+        content = share + NS + 1;
+        off = FIRST_CONTENT + (j - 1) * (int64_t)CONT_CONTENT;
+        cap = CONT_CONTENT;
     }
-    rfc6962_rec(roots, m, DIGEST, out32);
-    delete[] roots;
+    int64_t n = len - off;  // >= 1: every share of a blob holds some of it
+    if (n > cap) n = cap;
+    memcpy(content, c.data[b] + off, (size_t)n);
+    memset(content + n, 0, (size_t)(cap - n));
+    memcpy(out, ns, NS);
+    memcpy(out + NS, ns, NS);
+    sha256_one(msg, sizeof msg, out + 2 * NS);
 }
 
-static void run_striped(void (*fn)(void*, int, int), void* ctx, int count,
-                        int nthreads);
-
-// Batched commitment computation: ONE ctypes crossing for ALL blobs of a
-// proposal (512-PFB FilterTxs paid ~27 us of call overhead per blob).
-// Blob b's leaves are rows [blob_off[b], blob_off[b+1]) of the contiguous
-// leaves array; its mountain widths are sizes[size_off[b], size_off[b+1]).
-// Threaded across blobs.
-void create_commitments_batch(const uint8_t* leaves, int leaf_len,
-                              const int32_t* blob_off,
-                              const int32_t* sizes,
-                              const int32_t* size_off, int nblobs,
-                              uint8_t* out, int nthreads) {
-    if (nthreads <= 0) {
-        nthreads = (int)std::thread::hardware_concurrency();
-        if (nthreads <= 0) nthreads = 1;
+// NMT root of mountain m, its leaves folded on a stack of subtree roots
+// as they are hashed.
+static void mountain_root(const CommitCtx& c, int m, uint8_t* out) {
+    const int b = c.blob[m];
+    uint8_t stack[32][DIGEST];
+    int height[32];
+    int top = 0;
+    for (int i = 0; i < c.width[m]; i++) {
+        blob_leaf(c, b, c.first[m] + i, stack[top]);
+        height[top++] = 0;
+        while (top >= 2 && height[top - 1] == height[top - 2]) {
+            uint8_t node[DIGEST];
+            nmt_node(stack[top - 2], stack[top - 1], node);
+            memcpy(stack[top - 2], node, DIGEST);
+            height[top - 2]++;
+            top--;
+        }
     }
-    struct Ctx {
-        const uint8_t* leaves;
-        int leaf_len;
-        const int32_t* blob_off;
-        const int32_t* sizes;
-        const int32_t* size_off;
-        int nblobs;
-        uint8_t* out;
-    } ctx = {leaves, leaf_len, blob_off, sizes, size_off, nblobs, out};
-    run_striped(
-        [](void* p, int t, int nt) {
-            Ctx& c = *(Ctx*)p;
-            for (int b = t; b < c.nblobs; b += nt) {
-                const int n = c.blob_off[b + 1] - c.blob_off[b];
-                const int m = c.size_off[b + 1] - c.size_off[b];
-                create_commitment(
-                    c.leaves + (size_t)c.blob_off[b] * c.leaf_len, n,
-                    c.leaf_len, c.sizes + c.size_off[b], m,
-                    c.out + (size_t)b * 32);
-            }
-        },
-        &ctx, nblobs, nthreads);
+    memcpy(out, stack[0], DIGEST);
+}
+
+// Task t: the roots of its mountains.  The thread that finishes a blob's
+// last mountain folds the blob's roots into its commitment.
+static void commit_task(CommitCtx& c, int t) {
+    const int task = c.order[t];
+    for (int m = c.task_begin[task]; m < c.task_end[task]; m++) {
+        mountain_root(c, m, c.roots.data() + (size_t)m * DIGEST);
+        const int b = c.blob[m];
+        if (c.pending[b].fetch_sub(1, std::memory_order_acq_rel) == 1) {
+            const int m0 = c.size_off[b];
+            rfc6962_rec(c.roots.data() + (size_t)m0 * DIGEST,
+                        c.size_off[b + 1] - m0, DIGEST,
+                        c.out + (size_t)b * 32);
+        }
+    }
+}
+
+static void run_pool(void (*fn)(void*, int), void* ctx, int count,
+                     int nthreads);
+static int resolve_threads(int nthreads);
+
+// Commitments of nblobs blobs in one call, straight from their bytes.
+// Blob b is data[b] (lens[b] >= 1 bytes, namespace ns[b * NS ..], share
+// version versions[b]); its mountain widths (powers of two, summing to its
+// share count) are sizes[size_off[b] .. size_off[b + 1]).  The grain comes
+// from the input: a blob of more leaves than the grain is split into one
+// task per mountain, and smaller blobs are whole, gathered with their
+// neighbours into tasks of about the grain.  run_pool's queue hands out
+// the largest tasks first.  out: nblobs x 32.  Returns the number of tasks.
+int blob_commitments(const uint8_t* const* data, const int64_t* lens,
+                     const uint8_t* ns, const uint8_t* versions, int nblobs,
+                     const int32_t* sizes, const int32_t* size_off,
+                     uint8_t* out, int nthreads) {
+    CommitCtx c;
+    c.data = data;
+    c.lens = lens;
+    c.ns = ns;
+    c.versions = versions;
+    c.size_off = size_off;
+    c.out = out;
+    const int mountains = size_off[nblobs];
+    c.blob.resize(mountains);
+    c.first.resize(mountains);
+    c.width.assign(sizes, sizes + mountains);
+    c.roots.resize((size_t)mountains * DIGEST);
+    c.pending.reset(new std::atomic<int>[nblobs > 0 ? nblobs : 1]);
+    std::vector<int64_t> blob_leaves(nblobs);
+    int64_t leaves = 0;
+    for (int b = 0; b < nblobs; b++) {
+        c.pending[b].store(size_off[b + 1] - size_off[b]);
+        int64_t first = 0;
+        for (int m = size_off[b]; m < size_off[b + 1]; m++) {
+            c.blob[m] = b;
+            c.first[m] = first;
+            first += sizes[m];
+        }
+        blob_leaves[b] = first;
+        leaves += first;
+    }
+    int nt = resolve_threads(nthreads);
+    const int64_t most = (leaves + LEAVES_PER_THREAD - 1) / LEAVES_PER_THREAD;
+    if (nt > most) nt = (int)(most > 0 ? most : 1);
+    const int64_t per = (int64_t)nt * TASKS_PER_THREAD;
+    const int64_t grain = (leaves + per - 1) / per;
+    std::vector<int64_t> task_leaves;
+    auto add_task = [&](int begin, int end, int64_t n) {
+        c.task_begin.push_back(begin);
+        c.task_end.push_back(end);
+        task_leaves.push_back(n);
+    };
+    int open = -1;  // first mountain of the task small blobs are filling
+    int64_t filled = 0;
+    for (int b = 0; b < nblobs; b++) {
+        if (blob_leaves[b] > grain) {
+            if (open >= 0) add_task(open, size_off[b], filled);
+            open = -1;
+            for (int m = size_off[b]; m < size_off[b + 1]; m++)
+                add_task(m, m + 1, sizes[m]);
+            continue;
+        }
+        if (open < 0) {
+            open = size_off[b];
+            filled = 0;
+        }
+        filled += blob_leaves[b];
+        if (filled >= grain) {
+            add_task(open, size_off[b + 1], filled);
+            open = -1;
+        }
+    }
+    if (open >= 0) add_task(open, mountains, filled);
+    const int tasks = (int)task_leaves.size();
+    c.order.resize(tasks);
+    for (int t = 0; t < tasks; t++) c.order[t] = t;
+    std::stable_sort(c.order.begin(), c.order.end(), [&](int x, int y) {
+        return task_leaves[x] > task_leaves[y];
+    });
+    run_pool([](void* p, int t) { commit_task(*(CommitCtx*)p, t); }, &c,
+             tasks, nt);
+    return tasks;
 }
 
 // Batched per-axis GF(256) matmul: out[i] = D[i] (rows_out x k) * X[i]
@@ -502,6 +640,73 @@ static void run_striped(void (*fn)(void*, int, int), void* ctx, int count,
 // property the overlapped extend->roots phase depends on.  Task order is
 // PRESERVED in dispatch (item i is claimed before item i+n), which lets
 // a mixed phase list its latency-critical items first.
+//
+// The helper threads persist across calls, parked on a condition
+// variable: on a TPU v5e host, starting and joining a thread per call cost
+// ~0.25 ms per thread (13 threads 3.1 ms above one, for 0.4 ms of work),
+// more than a block's whole commitment pass at 13 threads.  One job runs
+// on the pool at a time; a call that finds it busy (a concurrent caller,
+// or a task that itself calls run_pool) starts threads of its own.
+struct WorkerPool {
+    std::mutex job;  // held by the caller whose job the pool runs
+    std::mutex m;    // guards everything below
+    std::condition_variable wake, done;
+    int size = 0;       // helper threads started
+    uint64_t gen = 0;   // bumped per job
+    int want = 0;       // helpers the current job may still take
+    int running = 0;    // helpers working on it
+    void (*fn)(void*, int) = nullptr;
+    void* ctx = nullptr;
+    int count = 0;
+    std::atomic<int> next{0};
+};
+
+static WorkerPool* g_pool = nullptr;
+static std::mutex g_pool_init;
+
+// Set while a thread runs a pool job's tasks: a task that calls run_pool
+// must not wait on the pool it is running on.
+static thread_local bool t_in_job = false;
+
+static void pool_drain(WorkerPool& p) {
+    t_in_job = true;
+    int i;
+    while ((i = p.next.fetch_add(1, std::memory_order_relaxed)) < p.count)
+        p.fn(p.ctx, i);
+    t_in_job = false;
+}
+
+static void pool_worker(WorkerPool* p) {
+    uint64_t seen = 0;
+    std::unique_lock<std::mutex> lk(p->m);
+    for (;;) {
+        p->wake.wait(lk, [&] { return p->gen != seen; });
+        seen = p->gen;
+        if (p->want <= 0) continue;
+        p->want--;
+        p->running++;
+        lk.unlock();
+        pool_drain(*p);
+        lk.lock();
+        if (--p->running == 0) p->done.notify_one();
+    }
+}
+
+// A forked child has none of the parent's threads: it starts a pool of
+// its own, leaving the parent's (whose locks may be held) untouched.
+static void pool_forget(void) { g_pool = nullptr; }
+
+static WorkerPool* pool_get(void) {
+    std::lock_guard<std::mutex> lk(g_pool_init);
+    if (g_pool == nullptr) {
+        static bool hooked = false;
+        if (!hooked) pthread_atfork(nullptr, nullptr, pool_forget);
+        hooked = true;
+        g_pool = new WorkerPool();  // lives as long as the process
+    }
+    return g_pool;
+}
+
 static void run_pool(void (*fn)(void*, int), void* ctx, int count,
                      int nthreads) {
     int nt = nthreads < count ? nthreads : count;
@@ -509,15 +714,35 @@ static void run_pool(void (*fn)(void*, int), void* ctx, int count,
         for (int i = 0; i < count; i++) fn(ctx, i);
         return;
     }
-    std::atomic<int> next(0);
-    auto work = [&]() {
-        int i;
-        while ((i = next.fetch_add(1, std::memory_order_relaxed)) < count)
-            fn(ctx, i);
-    };
-    std::vector<std::thread> ts;
-    for (int t = 0; t < nt; t++) ts.emplace_back(work);
-    for (auto& th : ts) th.join();
+    WorkerPool* p = pool_get();
+    std::unique_lock<std::mutex> job(p->job, std::defer_lock);
+    if (t_in_job || !job.try_lock()) {
+        std::atomic<int> next(0);
+        auto work = [&]() {
+            int i;
+            while ((i = next.fetch_add(1, std::memory_order_relaxed)) < count)
+                fn(ctx, i);
+        };
+        std::vector<std::thread> ts;
+        for (int t = 0; t < nt; t++) ts.emplace_back(work);
+        for (auto& th : ts) th.join();
+        return;
+    }
+    {
+        std::lock_guard<std::mutex> lk(p->m);
+        for (; p->size < nt - 1; p->size++) std::thread(pool_worker, p).detach();
+        p->fn = fn;
+        p->ctx = ctx;
+        p->count = count;
+        p->next.store(0, std::memory_order_relaxed);
+        p->want = nt - 1;
+        p->gen++;
+    }
+    p->wake.notify_all();
+    pool_drain(*p);  // the caller works too
+    std::unique_lock<std::mutex> lk(p->m);
+    p->want = 0;  // no helper joins a drained job
+    p->done.wait(lk, [&] { return p->running == 0; });
 }
 
 static int resolve_threads(int nthreads) {

@@ -255,6 +255,100 @@ def test_native_commitment_matches_python_path():
         assert got == nmt_ops.rfc6962_root_np(roots).tobytes(), nbytes
 
 
+def _bytes_for_shares(n):
+    """Blob length that fills exactly ``n`` sparse shares."""
+    return 478 + 482 * (n - 1)
+
+
+# 1 B, one byte either side of the first share and of the second, exact
+# multiples of 482 past the first share, and share counts either side of
+# every subtree-width step up to the threshold (ceil(n / 64) crossing a
+# power of two: widths 1, 2, 4, ..., 64)
+_COMMITMENT_SIZES = [1, 478, 479, 960, 961]
+_COMMITMENT_SIZES += [_bytes_for_shares(n) for n in (3, 7, 12)]
+for _n in (64, 128, 256, 512, 1024, 2048):
+    _COMMITMENT_SIZES += [_bytes_for_shares(_n), _bytes_for_shares(_n) + 1]
+
+# the share-commitment anchor of tests/test_reference_vectors.py: 5782
+# bytes of 0x02 under namespace v0(0x01 * 10)
+_ANCHOR_HEX = "3b0696ee3b902f2e2c91e338e866f4d6aa4876716dc76b91776ede1c683dbe2f"
+
+
+def _anchor_blob():
+    from celestia_tpu.da.blob import Blob
+    from celestia_tpu.da.namespace import Namespace
+
+    return Blob(Namespace.v0(b"\x01" * 10), b"\x02" * 5782)
+
+
+def _host_commitment(blob):
+    """blob_shares_array leaves, hashlib NMT roots, RFC-6962 fold."""
+    from celestia_tpu.appconsts import DEFAULT_SUBTREE_ROOT_THRESHOLD
+    from celestia_tpu.da import inclusion
+
+    leaves = inclusion._blob_leaves(blob)
+    sizes = inclusion._mountains(blob, DEFAULT_SUBTREE_ROOT_THRESHOLD)
+    roots, off = [], 0
+    for s in sizes:
+        roots.append(inclusion._nmt_root_host(leaves[off : off + s]))
+        off += s
+    return nmt_ops.rfc6962_root_np(roots).tobytes()
+
+
+@pytest.mark.parametrize("nthreads", [1, 8])
+@pytest.mark.parametrize("nbytes", _COMMITMENT_SIZES)
+def test_native_blob_commitments_match_host_path(nbytes, nthreads):
+    """The native pass, which lays each share out from the blob's bytes
+    as it hashes it, gives the bytes of the blob_shares_array + hashlib
+    path for one blob of every boundary size."""
+    from celestia_tpu.appconsts import DEFAULT_SUBTREE_ROOT_THRESHOLD
+    from celestia_tpu.da import inclusion
+    from celestia_tpu.da.blob import Blob
+    from celestia_tpu.da.namespace import Namespace
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    rng = np.random.default_rng(nbytes)
+    blob = Blob(
+        Namespace.v0(b"\x07" * 10),
+        rng.integers(0, 256, nbytes, dtype=np.uint8).tobytes(),
+    )
+    got, tasks = inclusion._compute_commitments(
+        [blob], DEFAULT_SUBTREE_ROOT_THRESHOLD, nthreads
+    )
+    assert got == [_host_commitment(blob)], nbytes
+    assert tasks == len(inclusion._mountains(blob, DEFAULT_SUBTREE_ROOT_THRESHOLD))
+
+
+@pytest.mark.parametrize("nthreads", [1, 8])
+@pytest.mark.parametrize("count", [1, 40])
+def test_native_blob_commitments_batch(count, nthreads):
+    """Mixed batches: every blob's commitment is its own (no bleed
+    between neighbours of different sizes and namespaces), the
+    first blob is the reference-vector anchor, and the hashlib path
+    agrees."""
+    from celestia_tpu.appconsts import DEFAULT_SUBTREE_ROOT_THRESHOLD
+    from celestia_tpu.da import inclusion
+    from celestia_tpu.da.blob import Blob
+    from celestia_tpu.da.namespace import Namespace
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    rng = np.random.default_rng(count)
+    blobs = [_anchor_blob()]
+    for i in range(1, count):
+        nbytes = _COMMITMENT_SIZES[(7 * i) % len(_COMMITMENT_SIZES)]
+        blobs.append(Blob(
+            Namespace.v0(b"batch-%03d" % i),
+            rng.integers(0, 256, nbytes, dtype=np.uint8).tobytes(),
+        ))
+    got, _ = inclusion._compute_commitments(
+        blobs, DEFAULT_SUBTREE_ROOT_THRESHOLD, nthreads
+    )
+    assert got[0].hex() == _ANCHOR_HEX
+    assert got == [_host_commitment(b) for b in blobs]
+
+
 def test_glv_split_invariant_and_bounds():
     """GLV decomposition (utils/secp256k1._glv_split): k1 + k2*lambda
     == k (mod N) with ~128-bit components, for random and boundary

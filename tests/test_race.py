@@ -136,13 +136,18 @@ def test_nonce_recovery_under_external_interference():
 def test_commitment_cache_concurrent_hammer():
     """Regression for the celint R1 founding bug: _COMMITMENT_CACHE shipped
     as an UNLOCKED plain dict mutated from pooled threads (warm_commitments
-    batches + per-blob create_commitment during FilterTxs/ProcessProposal).
-    Hammer the migrated shared-LRU cache from many threads with a tiny cap
-    so eviction churns constantly, and assert every commitment returned
-    under contention equals the serial recompute."""
+    batches + per-blob lookups during FilterTxs/ProcessProposal).
+    Hammer the shared LRU from many threads with a tiny cap so eviction
+    churns constantly, over both keys (the tx-digest key the validator
+    paths warm and read, the content key of create_commitment), and
+    assert every commitment returned under contention equals the serial
+    recompute."""
+    import hashlib
+
     from celestia_tpu.da.inclusion import (
         _COMMITMENT_CACHE,
         create_commitment,
+        tx_commitment,
         warm_commitments,
     )
 
@@ -150,6 +155,8 @@ def test_commitment_cache_concurrent_hammer():
         Blob(Namespace.v0(b"hammer-%02d" % i), bytes([i + 1]) * (300 + 37 * i))
         for i in range(24)
     ]
+    # one single-blob tx per blob; any fixed digest stands in for its raw
+    txs = [(hashlib.sha256(b"hammer-tx-%d" % i).digest(), (b,)) for i, b in enumerate(blobs)]
     old_cap = _COMMITMENT_CACHE.max_entries
     _COMMITMENT_CACHE.clear()
     try:
@@ -165,12 +172,15 @@ def test_commitment_cache_concurrent_hammer():
                 for rep in range(6):
                     if (tid + rep) % 3 == 0:
                         # the batch path pooled proposal legs use
-                        warm_commitments(blobs)
+                        warm_commitments(txs)
                     order = list(range(len(blobs)))
                     # deterministic per-thread order, distinct across threads
                     off = (tid * 5 + rep) % len(order)
                     for i in order[off:] + order[:off]:
-                        got = create_commitment(blobs[i])
+                        if (tid + i) % 2:
+                            got = create_commitment(blobs[i])
+                        else:
+                            got, _missed = tx_commitment(txs[i][0], 0, blobs[i])
                         assert got == expected[i], (
                             f"thread {tid} rep {rep} blob {i}: commitment "
                             f"diverged under concurrency"

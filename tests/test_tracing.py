@@ -569,6 +569,8 @@ def test_process_proposal_spans_cover_the_block(tracer):
     assert commitments["blobs"] == 6
     assert commitments["blob_bytes"] == 3 * (400 + 100)
     assert commitments["shares"] == 6
+    assert commitments["tasks"] == 6  # six one-share blobs: a task each
+    assert app.telemetry.summary()["counters"].get("commitment_warm_miss", 0) == 0
     assert by_name["ante.sig_verify"][0].args["verified"] == 3
     assert by_name["ante.sig_verify"][0].args["cache_hits"] == 0
     assert by_name["ante.chain"][0].args["txs"] == 3

@@ -23,11 +23,13 @@ CXX="${CXX:-g++}"
 PY="${PY:-python}"
 
 # the thread-scaling byte-identity suite: pooled native pipeline at
-# nthreads 1/2/4 pins identical extension, roots, data root and repair
+# nthreads 1/2/4 pins identical extension, roots, data root and repair,
+# and the blob commitment pass at 1 and 8 threads
 TESTS=(
   "tests/test_leopard_codec.py::test_threaded_host_pipeline_byte_identical"
   "tests/test_leopard_codec.py::test_golden_parity_vectors_pin_leopard_bytes"
   "tests/test_bench_smoke.py::test_threaded_extend_repair_dah_smoke"
+  "tests/test_golden_vectors.py::test_native_blob_commitments_batch"
 )
 
 # sanitizer-instrumented code needs frame pointers for usable reports;

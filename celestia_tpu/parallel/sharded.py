@@ -281,50 +281,62 @@ def extend_and_roots_sharded(
         )
 
 
+def _batch_span(squares: np.ndarray, mesh: Mesh):
+    """The ``extend.sharded`` span of one batched dispatch: the caller
+    opens it, :func:`_extend_and_roots_sharded_batch_device` and the
+    caller's own fetch open its children."""
+    return tracing.span(
+        "extend.sharded", k=squares.shape[1], batch=squares.shape[0],
+        mesh_data=int(mesh.shape["data"]), mesh_row=int(mesh.shape["row"]),
+        codec=_active_codec(),
+    )
+
+
+def _rows_to_eds(eds_local):
+    """(n, k, 2, 2k, B) row-shard layout -> (n, 2k, 2k, B), on device."""
+    return jnp.concatenate([eds_local[:, :, 0], eds_local[:, :, 1]], axis=1)
+
+
 def _extend_and_roots_sharded_batch_device(
     squares: np.ndarray, mesh: Mesh, *, count_squares: int = None
 ):
     """Batched sharded path, DEVICE-RESIDENT results: uint8[n,k,k,512],
-    n divisible by the data axis -> (eds_dev[n,2k,2k,512],
+    n divisible by the data axis -> (eds_local[n,k,2,2k,512] in the
+    row-shard layout (:func:`_rows_to_eds` reassembles it on device),
     row_roots[n,2k,90], col_roots[n,2k,90], data_roots[n,32]) with all
-    four still on their chips (per-square reassembly is one device-side
-    concatenate over the whole batch).  One device dispatch for the
-    whole batch — the state-sync catch-up leg (BASELINE.json config #5).
+    four still on their chips.  One device dispatch for the whole batch
+    — the state-sync catch-up leg (BASELINE.json config #5).  Runs
+    inside the caller's :func:`_batch_span`; traced, the host waits on
+    the device in ``extend.sharded.wait``.
 
     ``count_squares``: how many of the n inputs are REAL squares (the
     rest are data-axis padding the caller will drop) — only the real
     ones land in the mesh provider's sharded-extends counter."""
-    squares = np.asarray(squares, dtype=np.uint8)
     n, k = squares.shape[0], squares.shape[1]
     codec = _active_codec()
     data_ax, row_ax = int(mesh.shape["data"]), int(mesh.shape["row"])
-    with tracing.span(
-        "extend.sharded", k=k, batch=n, mesh_data=data_ax,
-        mesh_row=row_ax, codec=codec,
-    ):
+    with tracing.span("extend.sharded.upload", bytes=int(squares.nbytes)):
         sharding = NamedSharding(mesh, P("data", "row", None, None))
         x = jax.device_put(jnp.asarray(squares), sharding)
         devprof.record_transfer(
             "extend_sharded_batch", "h2d", int(squares.nbytes)
         )
-        fn = _sharded_fn(mesh, k, True, codec)
-        d = devprof.dispatch(
-            "extend_sharded_batch", multi_device=True,
-            k=k, batch=n, mesh=f"{data_ax}x{row_ax}", codec=codec,
-        )
-        out = d.done(fn(x))
-        eds_local, row_roots, col_roots, data_roots = out
-        # (n, k, 2, 2k, B) row-shard layout -> (n, 2k, 2k, B), on device
-        eds_dev = jnp.concatenate(
-            [eds_local[:, :, 0], eds_local[:, :, 1]], axis=1
-        )
+    fn = _sharded_fn(mesh, k, True, codec)
+    d = devprof.dispatch(
+        "extend_sharded_batch", multi_device=True,
+        k=k, batch=n, mesh=f"{data_ax}x{row_ax}", codec=codec,
+    )
+    with tracing.span("extend.sharded.launch"):
+        out = fn(x)
+    with tracing.span("extend.sharded.wait"):
+        out = d.done(out)
     devprof.note_compile("extend_sharded_batch", fn, (x,))
     from celestia_tpu.parallel import mesh as mesh_mod
 
     mesh_mod.record_sharded_extend(
         batched=True, squares=n if count_squares is None else count_squares
     )
-    return eds_dev, row_roots, col_roots, data_roots
+    return out
 
 
 def extend_and_roots_sharded_batch(
@@ -333,11 +345,14 @@ def extend_and_roots_sharded_batch(
     """Batched sharded path with the legacy HOST-array contract (see
     :func:`_extend_and_roots_sharded_batch_device`): all four results
     cross in ONE batched ``device_get``."""
-    eds_dev, row_roots, col_roots, data_roots = (
-        _extend_and_roots_sharded_batch_device(
-            squares, mesh, count_squares=count_squares
+    squares = np.asarray(squares, dtype=np.uint8)
+    with _batch_span(squares, mesh):
+        eds_local, row_roots, col_roots, data_roots = (
+            _extend_and_roots_sharded_batch_device(
+                squares, mesh, count_squares=count_squares
+            )
         )
-    )
+        eds_dev = _rows_to_eds(eds_local)
     with tracing.span("roots", stage="fetch", sharded=True):
         return devprof.fetch(
             "sharded_results", (eds_dev, row_roots, col_roots, data_roots)
@@ -399,25 +414,36 @@ def extend_and_headers_sharded_batch(
     shard_map leading dim must divide it) and drops the pad results; the
     state-sync warm path (state/app.py warm_extends_batched) does both
     and passes ``count_squares`` so pads never inflate the counter.
+    Traced, ``extend.sharded`` holds ``.upload``, ``.launch``, ``.wait``,
+    ``.fetch_roots`` and ``.assemble`` (the device-side reassembly, the
+    per-square slices and the DAHs).
     """
     from celestia_tpu.da.dah import ExtendedDataSquare
 
-    eds_dev, row_roots, col_roots, data_roots = (
-        _extend_and_roots_sharded_batch_device(
-            squares, mesh, count_squares=count_squares
-        )
-    )
-    # one batched root fetch for the WHOLE warm batch; each square's
-    # shares stay device-resident until someone reads them
-    rr, cc, drs = devprof.fetch(
-        "sharded_roots", (row_roots, col_roots, data_roots)
-    )
-    out: List[Tuple[object, object]] = []
-    for i in range(eds_dev.shape[0]):
-        out.append(
-            (
-                ExtendedDataSquare(eds_dev[i]),
-                _header_from_roots(rr[i], cc[i], drs[i]),
+    squares = np.asarray(squares, dtype=np.uint8)
+    n, k = squares.shape[0], squares.shape[1]
+    with _batch_span(squares, mesh):
+        eds_local, row_roots, col_roots, data_roots = (
+            _extend_and_roots_sharded_batch_device(
+                squares, mesh, count_squares=count_squares
             )
         )
+        # one batched root fetch for the WHOLE warm batch; each square's
+        # shares stay device-resident until someone reads them
+        with tracing.span(
+            "extend.sharded.fetch_roots",
+            bytes=n * (4 * k * NMT_DIGEST_SIZE + 32),
+        ):
+            rr, cc, drs = devprof.fetch(
+                "sharded_roots", (row_roots, col_roots, data_roots)
+            )
+        with tracing.span("extend.sharded.assemble"):
+            eds_dev = _rows_to_eds(eds_local)
+            out: List[Tuple[object, object]] = [
+                (
+                    ExtendedDataSquare(eds_dev[i]),
+                    _header_from_roots(rr[i], cc[i], drs[i]),
+                )
+                for i in range(n)
+            ]
     return out

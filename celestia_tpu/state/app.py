@@ -997,14 +997,35 @@ class App:
         cannot be rebuilt at the claimed size are skipped (the per-block
         validation will reject them with its usual reasons).  Never
         raises — any failure degrades to the per-block path (noted).
-        Returns the number of squares warmed."""
+        Returns the number of squares warmed.
+
+        Traced, the call is one ``extend_batch`` block trace (args ``k``
+        the largest claimed size, ``blocks``, ``mesh``, then ``pad`` and
+        ``warmed``): a ``square_build`` and an ``eds_cache.key`` span
+        (leg ``warm``) per block, ``extend_batch.stack``, and the
+        ``extend.sharded`` dispatch with its children."""
+        from celestia_tpu.parallel import mesh as mesh_mod
+
+        shape = mesh_mod.mesh_shape()
+        if shape is None:
+            return 0
+        with tracing.block_span(
+            "extend_batch",
+            height=self.next_height(),
+            k=max((size for _txs, size in blocks), default=0),
+            blocks=len(blocks),
+            mesh="%dx%d" % shape,
+        ) as root:
+            return self._warm_extends_batched_traced(blocks, root)
+
+    def _warm_extends_batched_traced(
+        self, blocks: List[Tuple[List[bytes], int]], root
+    ) -> int:
         from celestia_tpu.da import eds_cache
         from celestia_tpu.ops import gf256 as _gf256
         from celestia_tpu.parallel import mesh as mesh_mod
         from celestia_tpu.utils import faults
 
-        if mesh_mod.device_mesh() is None:
-            return 0
         codec = _gf256.active_codec()
         bound = self.max_effective_square_size()
         # group uncached, rebuildable squares by k (one batch per size)
@@ -1012,23 +1033,27 @@ class App:
         cached_hits = 0
         for block_txs, claimed_size in blocks:
             try:
-                key = eds_cache.make_key(
-                    block_txs, claimed_size, self.app_version, codec
-                )
+                with tracing.span("eds_cache.key", leg="warm"):
+                    key = eds_cache.make_key(
+                        block_txs, claimed_size, self.app_version, codec
+                    )
                 if eds_cache.CACHE.peek(key) is not None:
                     # counter-free probe that still refreshes recency:
                     # an already-cached window block must not sit
                     # LRU-oldest while the warm puts below evict it
                     cached_hits += 1
                     continue
-                square, _txs, _w = construct_square(list(block_txs), bound)
+                with tracing.span("square_build", txs=len(block_txs)):
+                    square, _txs, _w = construct_square(
+                        list(block_txs), bound
+                    )
                 if square.size != claimed_size:
                     continue  # per-block validation rejects it properly
                 by_k.setdefault(square.size, []).append((key, square))
             except Exception as e:
                 faults.note("mesh.batch_warm", e)
                 continue
-        warmed = 0
+        warmed = pads = 0
         # the cache is the hand-off: entries warmed beyond its capacity
         # would evict each other before the per-block validations read
         # them, turning the batched dispatch into pure extra work — ONE
@@ -1056,12 +1081,6 @@ class App:
             try:
                 from celestia_tpu.parallel import sharded
 
-                arr = np.stack(
-                    [
-                        sq.to_array().reshape(k, k, SHARE_SIZE)
-                        for _key, sq in items
-                    ]
-                )
                 # the shard_map leading dim must divide the data axis,
                 # and the jitted program is SHAPE-specialized — pad to a
                 # bucketed size (data_ax x next-pow2 chunks) by
@@ -1073,8 +1092,21 @@ class App:
                 if chunks > 1:
                     chunks = 1 << (chunks - 1).bit_length()
                 pad = data_ax * chunks - len(items)
-                if pad:
-                    arr = np.concatenate([arr, arr[-1:].repeat(pad, 0)])
+                with tracing.span(
+                    "extend_batch.stack", squares=len(items), pad=pad
+                ):
+                    arr = np.stack(
+                        [
+                            sq.to_array().reshape(k, k, SHARE_SIZE)
+                            for _key, sq in items
+                        ]
+                    )
+                    if pad:
+                        arr = np.concatenate(
+                            [arr, arr[-1:].repeat(pad, 0)]
+                        )
+                        self.telemetry.incr("extend_batch_pad", pad)
+                pads += pad
                 pairs = sharded.extend_and_headers_sharded_batch(
                     arr, m, count_squares=len(items)
                 )
@@ -1089,6 +1121,7 @@ class App:
                 )
                 self.telemetry.incr("extend_mesh_degraded")
                 break  # poisoned: remaining groups take the per-block path
+        root.annotate(pad=pads, warmed=warmed)
         return warmed
 
     def validate_blocks_batched(

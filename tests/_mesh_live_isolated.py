@@ -21,7 +21,8 @@ assertions would catch it if it did):
   live-path identity, EDS-cache interop both directions, laundering,
   fallback and the degradation ladder (the last three compile nothing).
 * group "datamesh": the 2x2 mixed data x row mesh, batched program —
-  batched-vs-loop root equality and the warm-only catch-up leg.
+  batched-vs-loop root equality, the warm-only catch-up leg, and the
+  catch-up leg's trace and bytes against the benchmark's reference.
 
 Between them both factorings are covered.
 """
@@ -261,3 +262,83 @@ def test_datamesh_batched_equals_loop_and_warm_cache():
         assert np.array_equal(pairs[i][0].shares, ref_eds.shares)
         assert pairs[i][1].hash == ref_dah.hash
         assert pairs[i][1].row_roots == ref_dah.row_roots
+
+
+def _reference():
+    """benchmark/configs/celestia_da_reference.py, loaded by path: a
+    plain implementation that imports nothing of the program."""
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "celestia_da_reference.py",
+    )
+    spec = importlib.util.spec_from_file_location("celestia_da_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_datamesh_catchup_trace_and_reference_bytes(monkeypatch):
+    """The catch-up leg on the real batched program (the one compiled
+    above): one ``extend_batch`` trace with the dispatch's children,
+    then a cache-hit ``process_proposal`` per block; the warmed EDS,
+    axis roots and data roots equal the plain reference's byte for
+    byte on a seeded pool."""
+    from celestia_tpu.utils import devprof, tracing
+
+    node, signer = _funded_node(b"mesh-catchup")
+    app = node.app
+    rng = np.random.default_rng(2025)
+    size = (8 * 8 // 4 - 4) * CONTINUATION_SPARSE_SHARE_CONTENT_SIZE
+    mesh_mod.configure("off")
+    proposals = []
+    for b in range(3):
+        blobs = [Blob(Namespace.v0(rng.bytes(10)), rng.bytes(size)) for _ in range(2)]
+        eds_cache.clear()
+        p = app.prepare_proposal([signer.pay_for_blob_tx(blobs)(sequence=0)])
+        proposals.append((p.block_txs, p.square_size, p.data_root))
+    assert {s for _t, s, _r in proposals} == {8}
+
+    eds_cache.clear()
+    mesh_mod._reset_for_tests()
+    mesh_mod.configure("2x2")
+    # the profiling build of the program would be a second CPU compile
+    monkeypatch.setattr(devprof, "note_compile", lambda *a, **k: None)
+    tracing.disable()
+    tracing.clear()
+    tracing.enable(16)
+    try:
+        verdicts = app.validate_blocks_batched(
+            [(list(t), s, r) for t, s, r in proposals]
+        )
+        traces = tracing.block_traces()
+    finally:
+        tracing.disable()
+        tracing.clear()
+    assert verdicts == [(True, "")] * 3
+    assert [t.name for t in traces] == ["extend_batch"] + ["process_proposal"] * 3
+    batch = traces[0]
+    by_id = {s.span_id: s for s in batch.spans}
+    root = by_id[batch.root_id]
+    assert (root.args["k"], root.args["blocks"], root.args["pad"]) == (8, 3, 1)
+    parent = {s.name: by_id[s.parent_id].name for s in batch.spans if s.parent_id in by_id}
+    for child in ("upload", "launch", "wait", "fetch_roots", "assemble"):
+        assert parent[f"extend.sharded.{child}"] == "extend.sharded"
+    assert parent["extend.sharded"] == "extend_batch"
+    for t in traces[1:]:
+        extend = next(s for s in t.spans if s.name == "extend")
+        assert extend.args["eds_cache"] == "hit"
+    assert app.telemetry.counters.get("extend_batch_pad", 0) == 1
+
+    ref_mod = _reference()
+    matrices: dict = {}
+    for txs, size, root_claimed in proposals:
+        key = eds_cache.make_key(txs, size, app.app_version, "leopard-ff8")
+        eds, dah = eds_cache.CACHE.peek(key)
+        ref = ref_mod.Reference(txs, _matrices=matrices)
+        assert ref.data_root == dah.hash == root_claimed
+        assert list(dah.row_roots) == ref.row_roots
+        assert list(dah.col_roots) == ref.col_roots
+        assert np.array_equal(np.asarray(eds.shares), ref.eds)

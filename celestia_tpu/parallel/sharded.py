@@ -6,18 +6,20 @@ square are sharded across the ``row`` mesh axis (ICI), whole squares are
 batched across the ``data`` axis (multi-block validator catch-up,
 BASELINE.json config #5).
 
-Communication pattern (all XLA collectives over ICI):
+Communication pattern (all XLA collectives over ICI; no reduction):
 
 * Q1 (row parity): fully local — each device encodes its own row shard.
-* Q2/Q3 (column parity): the GF(2) contraction runs over the sharded row
-  axis, so each device computes a partial bit-matmul against its slice of
-  the encode matrix, reduced with ``psum_scatter`` so every device ends up
-  holding only its shard of the parity rows (a reduce-scatter, not an
-  all-reduce — 1/R the traffic).
-* Row-tree NMT roots: local.  Column-tree NMT roots: each device reduces its
-  local rows of every column to one subtree node, then an ``all_gather`` of
-  those (tiny: R x 2k x 90 bytes) finishes the top log2(R) levels
-  replicated on every device.
+* Q2/Q3 (column parity): the top half is transposed across the row axis
+  instead of contracted over it.  One ``all_to_all`` of the packed bytes
+  hands device d all k rows of its 2k/R columns; it encodes those columns
+  locally with the full encode matrix (the same GF(2) product as Q1), and
+  a second ``all_to_all`` returns the parity rows row-sharded.  Each moves
+  (k/R) x 2k x 512 bytes per device per square, (R-1)/R of it off-chip
+  (:func:`ici_bytes`).
+* Row-tree NMT roots: local.  Column-tree NMT roots: each device holds
+  whole columns (both halves) for its 2k/R columns after the exchange, so
+  it hashes them as whole trees and an ``all_gather`` of the roots
+  (2k x 90 bytes) assembles them replicated.
 * Data root: row/col roots are all-gathered (2 x 2k x 90 bytes) and the
   RFC-6962 reduction is computed replicated — every device holds the same
   data root, the sharded analogue of the DAH hash at
@@ -58,8 +60,18 @@ def make_mesh(devices=None, data: int = 1, row: int = None) -> Mesh:
 
 
 def _extend_rows_local(q_top: jnp.ndarray, G: jnp.ndarray) -> jnp.ndarray:
-    """Row parity for the local row shard: (r, k, B) -> (r, k, B)."""
+    """Parity of each of r local rows (or, transposed, columns) of k
+    shares: (r, k, B) -> (r, k, B)."""
     return rs.pack_bits(rs.matmul_gf2(G, rs.unpack_bits(q_top)))
+
+
+def ici_bytes(k: int, n_row_shards: int, squares_per_device: int = 1) -> int:
+    """Bytes each device sends to the others in the column-parity
+    exchange of one dispatch: two all_to_alls of a (k/R, 2k, 512) uint8
+    block per square, of which (R-1)/R leaves the device (0 at R=1)."""
+    R = n_row_shards
+    block = (k // R) * 2 * k * SHARE_SIZE
+    return squares_per_device * 2 * block * (R - 1) // R
 
 
 def _sharded_extend_and_roots(square_shard: jnp.ndarray, G: jnp.ndarray, k: int,
@@ -68,54 +80,45 @@ def _sharded_extend_and_roots(square_shard: jnp.ndarray, G: jnp.ndarray, k: int,
     outputs (local EDS rows slice, replicated roots + data root)."""
     R = n_row_shards
     rows_local = k // R
+    cols_local = 2 * k // R
     shard_id = jax.lax.axis_index("row")
 
     # --- Q1: local row extension ------------------------------------------
     q1 = _extend_rows_local(square_shard, G)  # (k/R, k, B)
     top = jnp.concatenate([square_shard, q1], axis=1)  # (k/R, 2k, B)
 
-    # --- Q2/Q3: column parity via sharded contraction ---------------------
-    # Columns hold k values spread across the row shards; the encode matrix
-    # contracts over all 8k bit-rows.  Device d multiplies its (8k/R)-slice
-    # of G's columns with its local bits, then psum_scatter sums partials
-    # and scatters the 8k output bit-rows back across the row axis.
-    bits_local = rs.unpack_bits(top.transpose(1, 0, 2))  # (2k, 8*k/R, B)
-    g_cols = jax.lax.dynamic_slice_in_dim(
-        G, shard_id * (8 * rows_local), 8 * rows_local, axis=1
-    )  # (8k, 8k/R)
-    partial = jnp.matmul(g_cols, bits_local, preferred_element_type=jnp.int32)
-    # (2k, 8k, B) partial sums; reduce-scatter over the output bit-row axis.
-    partial = partial.transpose(1, 0, 2)  # (8k, 2k, B)
-    summed = jax.lax.psum_scatter(partial, "row", scatter_dimension=0, tiled=True)
-    bot_bits = (summed & 1).astype(jnp.int8)  # (8k/R, 2k, B)
-    bot = rs.pack_bits(
-        bot_bits.reshape(rows_local, 8, 2 * k, SHARE_SIZE)
-        .transpose(2, 0, 1, 3)
-        .reshape(2 * k, 8 * rows_local, SHARE_SIZE)
-    ).transpose(1, 0, 2)  # (k/R, 2k, B) local parity rows
-    # Note: psum_scatter gives contiguous slices in shard order, so device d
-    # holds parity rows [d*k/R, (d+1)*k/R) — same contiguous layout as Q0.
+    # --- Q2/Q3: column parity on whole local columns ------------------------
+    # Device d sends column block j of its rows to device j and receives
+    # all k rows (in global row order) of its own columns
+    # [d*2k/R, (d+1)*2k/R); it encodes them with the full G, and the
+    # reverse exchange hands back parity rows [d*k/R, (d+1)*k/R).
+    top_cols = jax.lax.all_to_all(
+        top, "row", split_axis=1, concat_axis=0, tiled=True
+    ).transpose(1, 0, 2)  # (2k/R, k, B)
+    bot_cols = _extend_rows_local(top_cols, G)  # (2k/R, k, B)
+    bot = jax.lax.all_to_all(
+        bot_cols.transpose(1, 0, 2), "row", split_axis=0, concat_axis=1,
+        tiled=True,
+    )  # (k/R, 2k, B) local parity rows
 
     # --- NMT leaves with namespace prefixes --------------------------------
-    # Global row indexes of this device's rows: top half r0+i, bottom half
-    # k + r0 + i; Q0 membership needs global (row, col) coordinates.
-    r0 = shard_id * rows_local
-    col_idx = jnp.arange(2 * k)
+    # Q0 membership needs the global indexes of a block's two cell axes
+    # (rows then columns, or columns then rows: the test is symmetric).
     parity_ns = jnp.asarray(_PARITY_NS)
 
-    def prefixed(rows, global_row_offset):
-        own = rows[..., :NAMESPACE_SIZE]
-        grow = global_row_offset + jnp.arange(rows.shape[0])
-        in_q0 = (grow[:, None] < k) & (col_idx[None, :] < k)
+    def prefixed(cells, idx0, idx1):
+        own = cells[..., :NAMESPACE_SIZE]
+        in_q0 = (idx0[:, None] < k) & (idx1[None, :] < k)
         pref = jnp.where(in_q0[..., None], own, jnp.broadcast_to(parity_ns, own.shape))
-        return jnp.concatenate([pref, rows], axis=-1)
+        return jnp.concatenate([pref, cells], axis=-1)
 
-    top_leaves = prefixed(top, r0)  # (k/R, 2k, 541)
-    bot_leaves = prefixed(bot, k + r0)
+    whole_axis = jnp.arange(2 * k)
+    own_rows = shard_id * rows_local + jnp.arange(rows_local)
+    own_cols = shard_id * cols_local + jnp.arange(cols_local)
 
     # --- row-tree roots: fully local ---------------------------------------
-    top_row_roots = nmt_ops.nmt_roots(top_leaves)  # (k/R, 90)
-    bot_row_roots = nmt_ops.nmt_roots(bot_leaves)
+    top_row_roots = nmt_ops.nmt_roots(prefixed(top, own_rows, whole_axis))
+    bot_row_roots = nmt_ops.nmt_roots(prefixed(bot, k + own_rows, whole_axis))
     row_roots = jnp.concatenate(
         [
             jax.lax.all_gather(top_row_roots, "row", axis=0, tiled=True),
@@ -124,30 +127,13 @@ def _sharded_extend_and_roots(square_shard: jnp.ndarray, G: jnp.ndarray, k: int,
         axis=0,
     )  # (2k, 90) replicated
 
-    # --- column-tree roots: local subtree reduce + gathered finish ---------
-    # Column-tree leaves are ordered by global row: [top rows..., bottom
-    # rows...].  Device d holds two contiguous leaf blocks per column (its Q0
-    # /Q1 rows and its Q2/Q3 rows); reduce each block to one subtree node,
-    # all_gather the 2R nodes per column (in global order), finish locally.
-    col_leaves_top = top_leaves.transpose(1, 0, 2)  # (2k cols, k/R, 541)
-    col_leaves_bot = bot_leaves.transpose(1, 0, 2)
-
-    def reduce_block(leaves):
-        nodes = nmt_ops.leaf_digests(leaves)
-        while nodes.shape[-2] > 1:
-            nodes = nmt_ops.combine_level(nodes)
-        return nodes[..., 0, :]  # (2k, 90)
-
-    sub_top = reduce_block(col_leaves_top)
-    sub_bot = reduce_block(col_leaves_bot)
-    # gather per-device subtree nodes in global row order
-    g_top = jax.lax.all_gather(sub_top, "row", axis=0)  # (R, 2k, 90)
-    g_bot = jax.lax.all_gather(sub_bot, "row", axis=0)
-    nodes = jnp.concatenate([g_top, g_bot], axis=0)  # (2R, 2k, 90)
-    nodes = nodes.transpose(1, 0, 2)  # (2k cols, 2R, 90)
-    while nodes.shape[-2] > 1:
-        nodes = nmt_ops.combine_level(nodes)
-    col_roots = nodes[..., 0, :]  # (2k, 90) replicated
+    # --- column-tree roots: whole local columns, gathered roots ------------
+    cols = jnp.concatenate([top_cols, bot_cols], axis=1)
+    # (2k/R cols, 2k leaves in global row order, B)
+    col_roots = jax.lax.all_gather(
+        nmt_ops.nmt_roots(prefixed(cols, own_cols, whole_axis)),
+        "row", axis=0, tiled=True,
+    )  # (2k, 90) replicated
 
     # --- data root ----------------------------------------------------------
     all_roots = jnp.concatenate([row_roots, col_roots], axis=0)  # (4k, 90)
@@ -220,8 +206,9 @@ def _extend_and_roots_sharded_device(
     shares host-side at all (da/device_plane.py contract: the only D2H
     of the proposal path is the roots).
 
-    Instrumented: an ``extend.sharded`` span with the mesh shape as args
-    (the live-path trace names the factoring) and a devprof dispatch
+    Instrumented: an ``extend.sharded`` span with the mesh shape and the
+    exchange's :func:`ici_bytes` as args (the live-path trace names the
+    factoring) and a devprof dispatch
     bracket that records the t1→t2 interval on EVERY chip the output is
     sharded across — device occupancy across chips is a measured number
     on the merged Perfetto timeline, not a guess.  ``record_stats=False``
@@ -233,7 +220,7 @@ def _extend_and_roots_sharded_device(
     data_ax, row_ax = int(mesh.shape["data"]), int(mesh.shape["row"])
     with tracing.span(
         "extend.sharded", k=k, mesh_data=data_ax, mesh_row=row_ax,
-        codec=codec,
+        codec=codec, ici_bytes=ici_bytes(k, row_ax),
     ):
         sharding = NamedSharding(mesh, P("row", None, None))
         x = jax.device_put(jnp.asarray(square), sharding)
@@ -285,10 +272,11 @@ def _batch_span(squares: np.ndarray, mesh: Mesh):
     """The ``extend.sharded`` span of one batched dispatch: the caller
     opens it, :func:`_extend_and_roots_sharded_batch_device` and the
     caller's own fetch open its children."""
+    n, k = squares.shape[0], squares.shape[1]
+    data_ax, row_ax = int(mesh.shape["data"]), int(mesh.shape["row"])
     return tracing.span(
-        "extend.sharded", k=squares.shape[1], batch=squares.shape[0],
-        mesh_data=int(mesh.shape["data"]), mesh_row=int(mesh.shape["row"]),
-        codec=_active_codec(),
+        "extend.sharded", k=k, batch=n, mesh_data=data_ax, mesh_row=row_ax,
+        codec=_active_codec(), ici_bytes=ici_bytes(k, row_ax, n // data_ax),
     )
 
 

@@ -101,6 +101,10 @@ def test_one_extend_batch_trace_then_a_cache_hit_per_block(catchup):
     assert {s.args["leg"] for s in batch.spans if s.name == "eds_cache.key"} == {"warm"}
     stack = next(s for s in batch.spans if s.name == "extend_batch.stack")
     assert stack.args == {"squares": 3, "pad": 1}
+    dispatch = next(s for s in batch.spans if s.name == "extend.sharded")
+    # 4 squares on one data row: per square, two all_to_alls each send 3
+    # of the 4 (k/4, 2k, 512) column blocks a chip holds
+    assert dispatch.args["ici_bytes"] == 4 * 2 * (8 // 4 * 16 * 512) * 3 // 4
     upload = next(s for s in batch.spans if s.name == "extend.sharded.upload")
     assert upload.args["bytes"] == 4 * 8 * 8 * 512
     fetch = next(s for s in batch.spans if s.name == "extend.sharded.fetch_roots")

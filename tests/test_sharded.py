@@ -9,11 +9,22 @@ of other programs (observed twice in full-suite runs; the identical tests
 pass in a fresh process, and __graft_entry__.dryrun_multichip re-execs into
 a clean child for the same reason).  Subprocess isolation keeps the
 multi-chip coverage without exposing the suite to that jaxlib crash.
+
+The structure test below only lowers the program (no backend compile),
+so it runs in this process.
 """
 
+import math
 import os
+import re
 import subprocess
 import sys
+
+import jax
+import jax.numpy as jnp
+
+from celestia_tpu.ops import gf256
+from celestia_tpu.parallel import sharded
 
 
 def _run_isolated(select: str) -> None:
@@ -44,3 +55,27 @@ def test_sharded_full_size_in_fresh_process():
     # the small-k programs in one process trips the same late-compile
     # jaxlib fragility the wrapper exists for — it gets its own child
     _run_isolated("full_size")
+
+
+_ALL_TO_ALL = re.compile(
+    r'"stablehlo\.all_to_all".*split_count = (\d+).*: \(tensor<([0-9x]+)xui8>\)'
+)
+
+
+def test_column_parity_is_an_exchange_not_a_reduction():
+    """The batched program on the cell's 1x4 factoring moves the packed
+    rows in all_to_alls and reduces nothing across chips, and the
+    ``ici_bytes`` its span reports is what those all_to_alls send."""
+    k, n, R = 8, 2, 4
+    mesh = sharded.make_mesh(jax.devices()[:R], data=1, row=R)
+    fn = sharded._sharded_fn(mesh, k, True, gf256.active_codec())
+    text = fn.lower(jax.ShapeDtypeStruct((n, k, k, 512), jnp.uint8)).as_text()
+    assert "all_reduce" not in text and "reduce_scatter" not in text
+    sent = []
+    for count, dims in _ALL_TO_ALL.findall(text):
+        block = math.prod(int(d) for d in dims.split("x"))
+        sent.append(block * (int(count) - 1) // int(count))
+    # one (k/R, 2k, 512) block per square out, one back
+    assert sent == [n * (k // R) * 2 * k * 512 * (R - 1) // R] * 2
+    assert sharded.ici_bytes(k, R, n) == sum(sent) == 49152
+    assert sharded.ici_bytes(k, 1) == 0

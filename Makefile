@@ -7,7 +7,7 @@ PY ?= python
 # passes --format through; exit codes are unchanged either way
 LINT_FORMAT ?=
 
-.PHONY: lint lockwatch test chaos trace-smoke profile-smoke incident-smoke critpath-smoke multichip-smoke das-smoke swarm-smoke ingress-smoke device-resident-smoke mesh-live t1-budget native native-sanitize native-sanitize-tsan native-sanitize-asan bench
+.PHONY: lint lockwatch test chaos trace-smoke profile-smoke incident-smoke critpath-smoke multichip-smoke das-smoke swarm-smoke ingress-smoke device-resident-smoke mesh-live t1-budget native native-sanitize native-sanitize-tsan native-sanitize-asan
 
 ## celint: concurrency & determinism static analysis (exit 1 on findings)
 lint:
@@ -160,5 +160,3 @@ native-sanitize-tsan:
 native-sanitize-asan:
 	bash tools/native_sanitize.sh asan
 
-bench:
-	$(PY) bench.py

@@ -169,7 +169,7 @@ def run(
 
 
 # ---------------------------------------------------------------------------
-# pre-signed PayForBlob traffic (bench.py, chip_smoke.py)
+# pre-signed PayForBlob traffic (chip_smoke.py)
 # ---------------------------------------------------------------------------
 
 

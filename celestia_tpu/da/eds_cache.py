@@ -88,7 +88,7 @@ class EdsCache:
     Thin domain wrapper over the unified :class:`LruCache` — the pair
     API (``put(key, eds, dah)``), the legacy stats keys and the min-DAH
     ``peek`` semantics are preserved byte-for-byte for existing callers
-    (bench.py, tests/test_eds_cache.py)."""
+    (tests/test_eds_cache.py)."""
 
     def __init__(self, max_entries: int = DEFAULT_MAX_ENTRIES):
         self._lru = LruCache(

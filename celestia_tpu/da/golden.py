@@ -1,7 +1,6 @@
-"""Golden vectors pinned from the reference Go stack, shared by the test
-suite (tests/test_reference_vectors.py) and the bench device-path gate
-(bench.py) so the pinned bytes and the fixture-share construction can
-never silently diverge between the two.
+"""Golden vectors pinned from the reference Go stack, kept in one module
+so the pinned bytes and the fixture-share construction used by the test
+suite (tests/test_reference_vectors.py) can never silently diverge.
 
 Sources (all in /root/reference):
 - pkg/da/data_availability_header_test.go:29  MinDataAvailabilityHeader hash

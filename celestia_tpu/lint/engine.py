@@ -318,9 +318,9 @@ def resolve_rules(names: Optional[Iterable[str]]) -> List[Rule]:
 
 
 class LintStats:
-    """Per-rule wall time + finding counts for ``--stats`` and bench's
-    ``extras.lint_stats`` — the whole-program pass must stay a watched
-    number, not a silently growing tax on tier-1."""
+    """Per-rule wall time + finding counts for ``--stats`` — the
+    whole-program pass must stay a watched number, not a silently
+    growing tax on tier-1."""
 
     def __init__(self):
         self.rules: Dict[str, dict] = {}

@@ -6,8 +6,8 @@ fast as its slowest serializing host round-trip — became mechanical
 telemetry in PR 11 (devprof dispatch brackets); this rule is the
 enforcement half: in ``da/``, ``ops/`` and ``state/`` a device value may
 only cross to the host through a devprof ``dispatch()`` bracket (whose
-``done()`` drains the device ON the profiled timeline) or an explicitly
-sanctioned function.  Banned forms: ``.item()``, bare
+``done()`` drains the device ON the profiled timeline) or a per-line
+allow with a reason.  Banned forms: ``.item()``, bare
 ``block_until_ready``, and ``np.asarray``/``np.array``/``float``/
 ``int``/``bool`` applied to a value the rule can infer is device-
 resident (assigned from a ``jnp.*`` call, ``jax.device_put``, or a call
@@ -44,16 +44,6 @@ _HOT_PREFIXES = (
     "celestia_tpu/state/",
 )
 
-# functions whose host syncs are the design, not an accident: diagnostic
-# breakdowns that exist to MEASURE the transfer boundary.  Entries are
-# (relpath, function name); keep this list short and argued — everything
-# else carries a per-line allow with a reason.
-HOT_SYNC_SANCTIONED: Tuple[Tuple[str, str], ...] = (
-    # three-sync variant kept for bench attribution; its docstring says
-    # "never on the hot path" and bench is its only caller
-    ("celestia_tpu/da/dah.py", "extend_and_header_breakdown"),
-)
-
 _JIT_FACTORY_SUFFIXES = ("_fn", "_jit", "_JIT")
 
 
@@ -83,8 +73,7 @@ class HostSyncRule(Rule):
         "value inferred device-resident (assigned from jnp.*, "
         "jax.device_put, or a jitted-program handle call) unless the "
         "value went through a devprof dispatch() bracket's done() — the "
-        "one sanctioned drain — or the enclosing function is on the "
-        "HOT_SYNC_SANCTIONED list (measurement paths).  Host round-trips "
+        "one sanctioned drain.  Host round-trips "
         "serialize the device pipeline (2108.02692); every survivor must "
         "be deliberate."
     )
@@ -93,12 +82,7 @@ class HostSyncRule(Rule):
         if not ctx.relpath.startswith(_HOT_PREFIXES):
             return
         aliases = _collect_aliases(ctx.tree)
-        sanctioned = {
-            fn for (rel, fn) in HOT_SYNC_SANCTIONED if rel == ctx.relpath
-        }
-        for scope_node, scope_name in _scopes(ctx.tree):
-            if scope_name in sanctioned:
-                continue
+        for scope_node in _scopes(ctx.tree):
             facts = _scope_facts(scope_node, aliases)
             yield from self._check_scope(ctx, scope_node, facts, aliases)
 
@@ -188,11 +172,11 @@ def _collect_aliases(tree: ast.AST) -> _Aliases:
 
 
 def _scopes(tree: ast.AST):
-    """(scope node, name) for the module body and every function."""
-    yield tree, "<module>"
+    """The module body and every function: one dataflow scope each."""
+    yield tree
     for node in ast.walk(tree):
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            yield node, node.name
+            yield node
 
 
 def _walk_scope(scope_node: ast.AST):

@@ -216,36 +216,6 @@ def eds_nmt_roots_host(eds: np.ndarray, nthreads=None) -> np.ndarray:
     return np.concatenate(chunks, axis=0).reshape(2, n2, NMT_DIGEST_SIZE)
 
 
-def nmt_roots_host_batch(leaves: np.ndarray, nthreads=None) -> np.ndarray:
-    """Roots of an ARBITRARY batch of NMTs on the host: uint8[T, n, L]
-    namespace-prefixed leaves -> uint8[T, 90], threaded.
-
-    The selective counterpart of :func:`eds_nmt_roots_host` — the row-memo
-    path in da/dah.py only needs the trees the memo missed (changed rows,
-    parity rows, columns), not all 4k.  Pool-sharded numpy: the memo's
-    native leg deliberately prefers the full fused C++ root pass over a
-    selective reduction (measured faster even with most rows memoized —
-    da/dah.py), so this only ever runs in the no-native fallback."""
-    from celestia_tpu.utils import hostpool
-
-    leaves = np.ascontiguousarray(leaves, dtype=np.uint8)
-    if leaves.ndim != 3:
-        raise ValueError(f"leaves must be [T, n, L], got {leaves.shape}")
-    T, n, _L = leaves.shape
-    if T == 0:
-        return np.zeros((0, NMT_DIGEST_SIZE), dtype=np.uint8)
-    if n & (n - 1):
-        raise ValueError(f"leaf count must be a power of two, got {n}")
-    workers = nthreads if nthreads is not None else hostpool.cpu_threads()
-    workers = max(1, min(int(workers), T))
-    bounds = np.linspace(0, T, workers + 1).astype(int)
-    chunks = hostpool.run_sharded(
-        lambda t: _nmt_roots_np_batch(leaves[bounds[t] : bounds[t + 1]]),
-        range(workers),
-    )
-    return np.concatenate(chunks, axis=0)
-
-
 def empty_root_np() -> np.ndarray:
     """EmptyRoot: zeros ns range + sha256 of the empty string."""
     import hashlib

@@ -948,8 +948,8 @@ class App:
         """ExtendBlock through the multi-chip mesh when the provider says
         so (parallel/mesh.py: >1 device visible / explicit --mesh, and
         the square's rows divide the row axis), else the single-device
-        path (da/dah.extend_block — host-native fast paths, row memo and
-        the jax leg all unchanged).  Byte-identity between the two legs
+        path (da/dah.extend_block: device plane, native host leg, jax
+        last rung).  Byte-identity between the two legs
         is test-pinned, so cache semantics and the data-root compare are
         oblivious to which one ran.
 

@@ -152,17 +152,6 @@ def reset() -> None:
     _seen_compiles.clear()
 
 
-def restart_window() -> None:
-    """Restart ONLY the occupancy window (busy counters + t0), keeping
-    the kernel/cost table and notes.  The bench leg uses it to exclude
-    the one-time AOT compile from the dispatch-occupancy measurement."""
-    global _window_t0
-    with _lock:
-        _busy_s.clear()
-        _dispatch_counts.clear()
-        _window_t0 = clock()
-
-
 def occupancy_probe() -> Optional[float]:
     """Occupancy percent over the interval since the PREVIOUS probe
     call — the CONTINUOUS sampler's gauge.  ``device_profile()``'s

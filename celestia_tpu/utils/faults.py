@@ -25,8 +25,8 @@ This module makes degradation first-class, tested code:
 * **Degradation telemetry.**  :func:`note` records exceptions that
   background/pooled threads deliberately survive (named by fault point,
   never silently dropped — the audit-sweep contract), and
-  :func:`fault_stats` exposes injected/recovered counts to bench.py's
-  ``extras.fault_stats``.
+  :func:`fault_stats` exposes injected/recovered counts to the
+  benchmark's degradation guard and the chaos suite.
 
 Reproduction: every schedule derives from ``CELESTIA_TPU_CHAOS_SEED``
 (or an explicit ``seed=``); ``CELESTIA_TPU_FAULTS`` takes
@@ -376,7 +376,7 @@ def record_degradation(subsystem: str, reason: str) -> None:
 
 
 def fault_stats() -> dict:
-    """Aggregate injection/recovery view for bench.py and the chaos
+    """Aggregate injection/recovery view for the benchmark and the chaos
     suite: per-point schedules + counters, swallow notes, degradations."""
     with _lock:
         return {

@@ -3,9 +3,9 @@
 The observability plane sees every *instrumented* span (utils/tracing.py),
 every device dispatch (utils/devprof.py) and every periodic snapshot
 (utils/timeseries.py) — but the host CPU between spans is a black box:
-bench's ``trace_summary`` pins real ``untraced_ms`` tails (the phase-3
-column-root tail, inter-phase glue, the ingress filter leg) that no span
-names.  This module closes that gap with a sampling profiler in the same
+the tracer's phase breakdown pins real ``untraced_ms`` tails (the
+phase-3 column-root tail, inter-phase glue, the ingress filter leg) that
+no span names.  This module closes that gap with a sampling profiler in the same
 bounded-structure, zero-overhead-disarmed idiom as the rest of the plane:
 
 * **Wall-clock sampling.**  A single daemon thread wakes at a
@@ -14,7 +14,7 @@ bounded-structure, zero-overhead-disarmed idiom as the rest of the plane:
   records one bounded stack per live thread.  No signals, no tracing
   hooks — the profiled code pays nothing per call; the only cost is the
   sampler's own tick, which is measured and reported as
-  ``overhead_pct`` (bench + ``tools/bench_check.py`` alarm at >2%).
+  ``overhead_pct`` (contract: under 2%).
 * **Span attribution.**  Each sample is joined to the sampled thread's
   ACTIVE span via :func:`tracing.thread_span` (the tid -> span registry
   the span tracer maintains), so a busy hostpool worker's frames land
@@ -245,7 +245,7 @@ def clear() -> None:
 
 def stats() -> dict:
     """Sampler accounting: rates and the measured self-overhead (the
-    figure bench records and tools/bench_check.py alarms on >2%)."""
+    ``overhead_pct`` the 2% contract is stated against)."""
     with _lock:
         end = _window_t1 if _window_t1 is not None else clock()
         window_s = max(0.0, end - _window_t0) if _window_t0 else 0.0

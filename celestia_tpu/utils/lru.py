@@ -19,8 +19,8 @@ thread-safety, stats — and each copy was one review away from drifting
   is ~32 MiB) and one of tiny digests can share a uniform policy.
 * **Unified stats.**  hits/misses/puts/replacements/evictions plus
   approximate resident bytes, per cache and aggregated process-wide via
-  :func:`registry_stats`, surfaced through utils/telemetry.py and
-  bench.py — production nodes get one knob and one dashboard, not five.
+  :func:`registry_stats`, surfaced through utils/telemetry.py —
+  production nodes get one knob and one dashboard, not five.
 
 celint rule R2 (no-handrolled-cache) forbids the OrderedDict+eviction
 pattern everywhere else in the tree, so the next cache MUST be built on

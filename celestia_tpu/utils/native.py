@@ -3,8 +3,7 @@
 Builds the shared object on demand with g++ (keyed by a stamp of the
 source and this host's resolved ``-march=native`` flags, so a tree copied
 to another CPU rebuilds) and exposes the same operations as the device
-kernels — used as the CPU comparison leg in bench.py and as a host
-fallback.  If no compiler is
+kernels — the host extend leg and the host fallbacks.  If no compiler is
 available the module degrades gracefully (``available()`` returns False).
 """
 
@@ -322,9 +321,9 @@ def eds_nmt_roots(eds: np.ndarray, nthreads: Optional[int] = None) -> np.ndarray
 def extend_block_cpu(square: np.ndarray, nthreads: Optional[int] = None):
     """Full CPU ExtendBlock: square -> (eds, axis roots, data root).
 
-    Threaded native pipeline with the extend->roots overlap — the honest
-    CPU comparison leg for bench.py (role of Leopard-RS + crypto/sha256
-    in the reference, SURVEY.md §2.2).
+    Threaded native pipeline with the extend->roots overlap under the
+    table-method codec (role of Leopard-RS + crypto/sha256 in the
+    reference, SURVEY.md §2.2).
     """
     from celestia_tpu.utils import faults
 
@@ -353,7 +352,7 @@ def extend_block_cpu(square: np.ndarray, nthreads: Optional[int] = None):
 def leo_encode(data: np.ndarray) -> np.ndarray:
     """Leopard FFT encode of one axis: data uint8[k, B] -> parity
     uint8[k, B] (O(k log k); codec-independent — always the leopard
-    code, used for cross-validation and the bench leg)."""
+    code, used for cross-validation)."""
     lib = _load()
     if lib is None:
         raise RuntimeError("native library unavailable")
@@ -415,9 +414,9 @@ def extend_block_leopard_cpu(
     square: np.ndarray, nthreads: Optional[int] = None
 ):
     """Full CPU ExtendBlock via the Leopard O(n log n) FFT codec:
-    square -> (eds, axis roots, data root).  The honest vs_leopard_cpu
-    comparison leg for bench.py (the reference's codec class at full
-    size, same SHA/NMT stage as extend_block_cpu)."""
+    square -> (eds, axis roots, data root): the host extend leg at the
+    default codec (the reference's codec class at full size, same
+    SHA/NMT stage as extend_block_cpu)."""
     from celestia_tpu.utils import faults
 
     faults.fire("native.extend")
@@ -545,10 +544,6 @@ def ecmul_double_batch(
 
 def has_glv() -> bool:
     return _load() is not None and _has_glv
-
-
-def has_glv_pre() -> bool:
-    return _load() is not None and _has_glv_pre
 
 
 # below this many live verifies the _pre symbol's per-stripe table

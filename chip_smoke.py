@@ -378,7 +378,7 @@ def check_device_path(platform):
     n_plane = spans.get("extend.device_plane", {}).get("count", 0)
     host_legs = {
         n: spans[n]["count"]
-        for n in ("extend.native", "extend.jax", "extend.memo", "extend.sharded")
+        for n in ("extend.native", "extend.jax", "extend.sharded")
         if spans.get(n, {}).get("count", 0)
     }
     check(n_plane > 0, "no extend went through extend.device_plane")

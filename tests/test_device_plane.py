@@ -8,6 +8,9 @@ wiring, same programs, host-scale buffers.  The consensus-safety
 contract under test: a plane-extended block commits the SAME roots and
 serves the SAME proof bytes as the host pipeline, and losing the device
 (eviction, fault) degrades to the host paths without changing a byte.
+The extend route's three legs (device plane, native host leg, jax last
+rung) are each checked against the benchmark's independent reference,
+and their order is pinned.
 """
 
 import numpy as np
@@ -17,7 +20,7 @@ from celestia_tpu.da import dah as dah_mod
 from celestia_tpu.da import das as das_mod
 from celestia_tpu.da import device_plane, eds_cache
 from celestia_tpu.ops import gf256
-from celestia_tpu.utils import devprof
+from celestia_tpu.utils import devprof, faults, native, tracing
 
 K = 4
 
@@ -33,11 +36,14 @@ def _square(k: int = K, seed: int = 12) -> np.ndarray:
 @pytest.fixture(autouse=True)
 def _clean_plane():
     """Every test starts unpoisoned with an empty device-handle cache
-    and leaves the process the same way (the plane state is global)."""
+    and leaves the process the same way (the plane state is global):
+    no poison pin and no degradation record outlives its test."""
     device_plane.clear_poison(force=True)
     eds_cache.clear()
     yield
     device_plane.clear_poison(force=True)
+    native.clear_poison(force=True)
+    faults.reset_stats()
     eds_cache.clear()
 
 
@@ -253,3 +259,131 @@ def test_mode_env_routing(monkeypatch):
 
     if host_regime():
         assert not device_plane.enabled()
+
+
+# ---------------------------------------------------------------------------
+# the extend route: three legs, each against the independent reference,
+# and the order they are tried in
+# ---------------------------------------------------------------------------
+
+_LEG_SPANS = ("extend.device_plane", "extend.native", "extend.jax")
+
+
+def _reference():
+    """benchmark/configs/celestia_da_reference.py, loaded by path: a
+    plain implementation that imports nothing of the program."""
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "celestia_da_reference.py",
+    )
+    spec = importlib.util.spec_from_file_location("celestia_da_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _sorted_ns_square(k: int, seed: int) -> np.ndarray:
+    """Random shares whose namespaces ascend in row-major order (so down
+    every column too), as a built square's do: the NMT min/max rules of
+    the program and the reference agree only on ordered leaves."""
+    rng = np.random.default_rng(seed)
+    sq = rng.integers(0, 256, (k, k, 512), dtype=np.uint8)
+    ns = rng.integers(0, 256, (k * k, 29), dtype=np.uint8)
+    ns[:, 0] = 0  # version 0, never the parity namespace
+    sq[:, :, :29] = ns[np.lexsort(ns.T[::-1])].reshape(k, k, 29)
+    return sq
+
+
+def _traced_extend(sq: np.ndarray):
+    """(eds, dah, names of the extend legs that ran) for one square."""
+    tracing.disable()
+    tracing.clear()
+    tracing.enable(4)
+    try:
+        with tracing.block_span("extend_leg", height=1):
+            eds, dah = dah_mod.extend_and_header(sq.copy())
+        ran = {s.name for s in tracing.block_traces()[-1].spans}
+    finally:
+        tracing.disable()
+        tracing.clear()
+    return eds, dah, ran & set(_LEG_SPANS)
+
+
+@pytest.mark.parametrize(
+    "leg,k",
+    [("native", k) for k in (1, 2, 4, 8, 16, 32, 64)]
+    + [("plane", k) for k in (1, 2, 4)]
+    + [("jax", k) for k in (1, 2, 4)],
+)
+def test_extend_legs_match_reference(leg, k):
+    """Each leg, forced, gives the reference's EDS bytes, all 4k axis
+    roots and the data root, at Leopard."""
+    if leg == "native" and not native.available():
+        pytest.skip("native library not built")
+    ref_mod = _reference()
+    sq = _sorted_ns_square(k, seed=40 + k)
+    prev = gf256.active_codec()
+    try:
+        gf256.set_active_codec(gf256.CODEC_LEOPARD)
+        with device_plane.forced("on" if leg == "plane" else "off"):
+            if leg == "jax":
+                native.poison("test: force the last rung")
+            eds, dah, ran = _traced_extend(sq)
+    finally:
+        native.clear_poison(force=True)
+        gf256.set_active_codec(prev, force=True)
+    assert ran == {f"extend.{'device_plane' if leg == 'plane' else leg}"}
+    ref_eds = ref_mod.extend(sq, ref_mod.leopard_bit_matrix(k))
+    ref_rows, ref_cols = ref_mod.axis_roots(ref_eds)
+    assert np.array_equal(np.asarray(eds.shares), ref_eds)
+    assert list(dah.row_roots) == ref_rows
+    assert list(dah.col_roots) == ref_cols
+    assert dah.hash == ref_mod.rfc6962_root(ref_rows + ref_cols)
+
+
+@pytest.mark.parametrize(
+    "codec", [gf256.CODEC_LEOPARD, gf256.CODEC_LAGRANGE]
+)
+@pytest.mark.parametrize(
+    "state,expect",
+    [
+        ("clean", "extend.device_plane"),
+        ("plane_poisoned", "extend.native"),
+        ("plane_off_on_chip", "extend.native"),
+        ("plane_and_native_poisoned", "extend.jax"),
+    ],
+)
+def test_extend_ladder_takes_next_leg(state, expect, codec, monkeypatch):
+    """The route tries the device plane, then the native host leg, then
+    the jax last rung — and a chip host whose plane is off takes the
+    host leg, not a second device program.  Every state's DAH equals the
+    clean state's."""
+    from celestia_tpu.utils import device
+
+    if not native.available():
+        pytest.skip("native library not built")
+    sq = _square(seed=30)
+    prev = gf256.active_codec()
+    try:
+        gf256.set_active_codec(codec)
+        with device_plane.forced("on"):
+            _, dah_ref, _ = _traced_extend(sq)
+            if state in ("plane_poisoned", "plane_and_native_poisoned"):
+                device_plane.poison("test: injected device fault")
+            if state == "plane_and_native_poisoned":
+                native.poison("test: injected native fault")
+        mode = "off" if state == "plane_off_on_chip" else "on"
+        if state == "plane_off_on_chip":
+            monkeypatch.setattr(device, "host_regime", lambda: False)
+        with device_plane.forced(mode):
+            _, dah, ran = _traced_extend(sq)
+    finally:
+        native.clear_poison(force=True)
+        gf256.set_active_codec(prev, force=True)
+    assert ran == {expect}, ran
+    assert dah.hash == dah_ref.hash
+    assert dah.row_roots == dah_ref.row_roots
+    assert dah.col_roots == dah_ref.col_roots

@@ -1,6 +1,6 @@
 """Device-backend facts and compile-cache placement (utils/device.py), and
-the no-fallback contract of the chip entry points: with no accelerator,
-bench.py and chip_smoke.py fail instead of carrying on with the CPU."""
+the no-fallback contract of the chip entry point: with no accelerator,
+chip_smoke.py fails instead of carrying on with the CPU."""
 
 import json
 import os
@@ -73,7 +73,7 @@ def _run(args, cwd):
 
 @pytest.mark.parametrize(
     "script,needle",
-    [("chip_smoke.py", "no TPU found"), ("bench.py", "no accelerator found")],
+    [("chip_smoke.py", "no TPU found")],
 )
 def test_chip_entry_points_refuse_the_cpu(script, needle):
     out = _run([script], REPO)

@@ -1,5 +1,5 @@
-"""Proposal-lifecycle DA caching: content-addressed EDS/DAH cache, row
-memoization and the decode-once pipeline (PR 5).
+"""Proposal-lifecycle DA caching: the content-addressed EDS/DAH cache and
+the decode-once pipeline.
 
 The safety-critical properties pinned here:
 
@@ -8,7 +8,6 @@ The safety-critical properties pinned here:
   launder a bad square through a cache hit;
 * cached and uncached paths are byte-identical (DAH hash equality for
   both codecs, single- and multi-threaded);
-* the row memo's assembled squares equal the fused pipeline's bit for bit;
 * the codec is pinned once at genesis: switching after first native use
   hard-fails outside tests.
 """
@@ -30,10 +29,8 @@ from celestia_tpu.utils.secp256k1 import PrivateKey
 @pytest.fixture(autouse=True)
 def _fresh_caches():
     eds_cache.clear()
-    dah_mod.clear_row_memo()
     yield
     eds_cache.clear()
-    dah_mod.clear_row_memo()
 
 
 def _funded_app(seed=b"eds-cache", codec=None, chain_id="edscache-1"):
@@ -117,7 +114,6 @@ def test_proposer_process_leg_hits_cache_and_matches_cold_run():
     assert app.telemetry.counters.get("eds_cache_hit_process") == 1
     # byte-identical to a fully cold validator on the same genesis
     eds_cache.clear()
-    dah_mod.clear_row_memo()
     cold, _ = _funded_app()
     ok, reason = cold.process_proposal(
         prop.block_txs, prop.square_size, prop.data_root
@@ -209,88 +205,11 @@ def test_cache_hit_dah_byte_identical_to_cold(codec, threads):
         eds_hit, dah_hit = cached_entry
         # cold recompute: every cache emptied
         eds_cache.clear()
-        dah_mod.clear_row_memo()
         prop_cold = app.prepare_proposal(txs)
         assert prop_cold.dah.hash == dah_hit.hash
         assert prop_cold.dah.row_roots == dah_hit.row_roots
         assert prop_cold.dah.col_roots == dah_hit.col_roots
         assert np.array_equal(prop_cold.eds.shares, eds_hit.shares)
-    finally:
-        hostpool.set_cpu_threads(prev_threads)
-        gf256.set_active_codec(prev_codec)
-
-
-@pytest.mark.parametrize("codec", [gf256.CODEC_LEOPARD, gf256.CODEC_LAGRANGE])
-@pytest.mark.parametrize("threads", [1, None])
-@pytest.mark.parametrize("use_native", [True, False])
-def test_row_memo_assembly_byte_identical(codec, threads, use_native, monkeypatch):
-    """The memoized assembly path (warm rows) must equal the fused
-    pipeline bit for bit: EDS bytes, all 4k roots, the data root.
-
-    Production scoping disables the memo for leopard+native (the fused
-    C++ pipeline beats Python-orchestrated reuse even at 100% coverage —
-    see the measured note in da/dah.py), so the assembly path is forced
-    on here: byte identity must hold for BOTH codecs regardless of when
-    the policy chooses to engage it.  use_native=False runs the WARM
-    (assembly) legs with the native library masked — pinning the pure-
-    Python assembly + selective nmt_roots_host_batch fallback (the leg
-    every no-native deployment depends on) against the native fused
-    reference bytes, even on native-built hosts."""
-    from contextlib import contextmanager
-
-    from celestia_tpu.utils import native as native_mod
-
-    if not native_mod.available():
-        if use_native:
-            pytest.skip("native library not built")
-        # no-native host: the plain parametrization already covers the
-        # fallback; skip the redundant (and jax-compile-heavy) variant
-        pytest.skip("native library not built; fallback covered by default")
-
-    @contextmanager
-    def warm_env():
-        """Native masked during the assembly legs when use_native=False;
-        cold references always use the fast native pipeline."""
-        if use_native:
-            yield
-            return
-        orig = native_mod.available
-        native_mod.available = lambda: False
-        try:
-            yield
-        finally:
-            native_mod.available = orig
-
-    prev_codec = gf256.active_codec()
-    prev_threads = hostpool._override
-    try:
-        gf256.set_active_codec(codec)
-        hostpool.set_cpu_threads(threads)
-        monkeypatch.setattr(dah_mod, "_row_memo_applicable", lambda: True)
-        rng = np.random.default_rng(5)
-        for k in (4, 8):
-            sq = rng.integers(0, 256, (k, k, 512), dtype=np.uint8)
-            dah_mod.clear_row_memo()
-            eds_cold, dah_cold = dah_mod.extend_and_header(sq)
-            assembled_before = dah_mod.row_memo_stats()["assembled"]
-            with warm_env():
-                eds_warm, dah_warm = dah_mod.extend_and_header(sq)
-            assert dah_mod.row_memo_stats()["assembled"] == assembled_before + 1
-            assert np.array_equal(eds_warm.shares, eds_cold.shares), (codec, k)
-            assert dah_warm.hash == dah_cold.hash
-            assert dah_warm.row_roots == dah_cold.row_roots
-            assert dah_warm.col_roots == dah_cold.col_roots
-            # partial overlap: change half the rows, keep half
-            sq2 = sq.copy()
-            sq2[: k // 2] = rng.integers(
-                0, 256, (k // 2, k, 512), dtype=np.uint8
-            )
-            with warm_env():
-                eds2_warm, dah2_warm = dah_mod.extend_and_header(sq2)
-            dah_mod.clear_row_memo()
-            eds2_cold, dah2_cold = dah_mod.extend_and_header(sq2)
-            assert np.array_equal(eds2_warm.shares, eds2_cold.shares)
-            assert dah2_warm.hash == dah2_cold.hash
     finally:
         hostpool.set_cpu_threads(prev_threads)
         gf256.set_active_codec(prev_codec)
@@ -411,7 +330,7 @@ def test_set_active_codec_refuses_switch_after_native_use(monkeypatch):
 
 def test_lost_cache_writes_force_recompute_never_partial(chaos):
     """With lru.put faults armed every insert is a LOST WRITE: the
-    EDS/DAH cache and row memo must simply miss and recompute — an entry
+    EDS/DAH cache must simply miss and recompute — an entry
     is either absent or complete, and the recomputed bytes match a
     fault-free run exactly."""
     app, key = _funded_app(b"chaos-lru")
@@ -419,7 +338,6 @@ def test_lost_cache_writes_force_recompute_never_partial(chaos):
     prop_clean = app.prepare_proposal(txs)
 
     eds_cache.clear()
-    dah_mod.clear_row_memo()
     chaos.arm("lru.put", "fail_rate", rate=1.0, seed=13)
     app2, key2 = _funded_app(b"chaos-lru")
     txs2 = _send_txs(app2, key2)

@@ -1,11 +1,9 @@
-"""Smoke coverage for the pooled host DA pipeline under tier-1.
+"""The pooled host DA pipeline at tiny k with an explicit 2-thread pool.
 
-bench.py itself is too slow for the tier-1 gate (k=128, many legs), so
-this exercises the same NEW threaded paths once at tiny k with an
-explicit 2-thread pool: the hostpool config chain, the overlapped native
-extend->roots pipeline, the pooled host repair, the host-regime DAH fast
-path, and the no-native numpy fallbacks — all asserted byte-identical to
-their reference constructions.
+Covers the hostpool configuration chain (``--cpu-threads``), the
+overlapped native extend->roots pipeline, the pooled host repair, the
+host DAH fast path and the no-native numpy fallbacks, each asserted
+byte-identical to its reference construction.
 """
 
 import numpy as np

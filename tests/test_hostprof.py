@@ -59,8 +59,7 @@ def test_sampler_thread_collects_continuously():
     # modest expectations on purpose: this runs on a contended 1-core
     # CI host mid-suite, where GIL pressure can starve the sampler
     # thread's wakeups — the test proves the thread LIVES and collects;
-    # the 2% overhead contract is pinned on bench's quiet leg
-    # (extras.host_profile + the bench_check ceiling), not here
+    # the 2% overhead contract is a quiet-host figure, not pinned here
     hostprof.start(250.0)
     deadline = clock() + 0.3
     _busy_until(deadline)

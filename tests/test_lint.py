@@ -880,14 +880,6 @@ def test_r7_scoped_to_hot_path_packages():
     assert _ids(_lint(R7_BAD_EACH_FORM, "celestia_tpu/utils/fixture.py", ["r7"])) == []
 
 
-def test_r7_sanctioned_function_is_exempt():
-    from celestia_tpu.lint.hotpath import HOT_SYNC_SANCTIONED
-
-    assert ("celestia_tpu/da/dah.py", "extend_and_header_breakdown") in (
-        HOT_SYNC_SANCTIONED
-    )
-
-
 def test_r7_allow_with_reason_suppresses():
     src = """
         import jax

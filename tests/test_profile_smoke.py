@@ -16,28 +16,27 @@ from celestia_tpu.utils import devprof, tracing
 
 
 @pytest.fixture
-def traced_jax_node(monkeypatch):
+def traced_jax_node():
     """A tiny funded TestNode whose extension is FORCED through the
-    jitted jax leg (the device path's code shape): without the patch the
-    native fused pipeline or the row memo would satisfy the square
-    host-side and no device dispatch would happen on the CPU backend."""
-    from celestia_tpu.da import dah as dah_mod
-    from celestia_tpu.da import eds_cache
+    device plane (the leg the chip runs): without it the native fused
+    pipeline would satisfy the square host-side and no device dispatch
+    would happen on the CPU backend."""
+    from celestia_tpu.da import device_plane, eds_cache
     from celestia_tpu.node.testnode import TestNode
     from celestia_tpu.utils.secp256k1 import PrivateKey
 
-    monkeypatch.setattr(dah_mod, "_host_native_available", lambda: False)
-    monkeypatch.setattr(dah_mod, "_row_memo_applicable", lambda: False)
     tracing.enable(4)
     tracing.clear()
     devprof.reset()
     eds_cache.clear()
-    key = PrivateKey.from_seed(b"test-profile-smoke")
-    node = TestNode(funded_accounts=[(key, 10**12)], auto_produce=False)
-    yield node, key
+    with device_plane.forced("on"):
+        key = PrivateKey.from_seed(b"test-profile-smoke")
+        node = TestNode(funded_accounts=[(key, 10**12)], auto_produce=False)
+        yield node, key
     tracing.disable()
     tracing.clear()
     devprof.reset()
+    eds_cache.clear()
 
 
 def _produce_send_block(node, key):
@@ -63,9 +62,9 @@ def test_traced_block_has_host_and_device_tracks(traced_jax_node):
     host = [s for s in prep.spans if s.cat != "device"]
     device = [s for s in prep.spans if s.cat == "device"]
     assert host and device, sorted({s.name for s in prep.spans})
-    # the device span is the fused extend+roots dispatch, on a synthetic
-    # per-chip track, parented under the block's extend leg
-    assert any(s.name == "device.extend_and_roots" for s in device)
+    # the device span is the fused extend_levels dispatch, on a
+    # synthetic per-chip track, parented under the block's extend leg
+    assert any(s.name == "device.extend_levels" for s in device)
     for s in device:
         assert s.tid >= devprof.DEVICE_TID_BASE
         assert s.thread_name.startswith("device:")
@@ -83,9 +82,9 @@ def test_traced_block_has_host_and_device_tracks(traced_jax_node):
     # the build runs on a background thread — join it first
     devprof.flush_compiles()
     prof = devprof.device_profile()
-    assert "extend_and_roots" in prof["kernels"], prof["notes"]
-    assert prof["kernels"]["extend_and_roots"]["compile_ms"] > 0.0
-    assert prof["dispatches"].get("extend_and_roots", 0) >= 1
+    assert "extend_levels" in prof["kernels"], prof["notes"]
+    assert prof["kernels"]["extend_levels"]["compile_ms"] > 0.0
+    assert prof["dispatches"].get("extend_levels", 0) >= 1
 
 
 def test_timeseries_alert_and_exposition_over_live_node(traced_jax_node):
@@ -127,7 +126,7 @@ def test_timeseries_alert_and_exposition_over_live_node(traced_jax_node):
         service.alert_engine = engine
         text = service.metrics_text()
         assert validate_exposition(text) == []
-        assert 'celestia_tpu_xla_compile_ms{kernel="extend_and_roots"}' in text
+        assert 'celestia_tpu_xla_compile_ms{kernel="extend_levels"}' in text
         assert "celestia_tpu_trace_span_drops_total" in text
         assert (
             'celestia_tpu_alert_firing{rule="degradations_above_base"} 1'

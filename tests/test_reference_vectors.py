@@ -48,8 +48,7 @@ from celestia_tpu.da.inclusion import create_commitment
 from celestia_tpu.da.namespace import Namespace
 from celestia_tpu.utils import native
 
-# Pinned bytes + fixture-share construction live in celestia_tpu.da.golden,
-# shared with bench.py's on-device fixture gate.
+# Pinned bytes + fixture-share construction live in celestia_tpu.da.golden.
 from celestia_tpu.da.golden import (  # noqa: F401
     DAH_2X2_HASH,
     DAH_128_HASH,

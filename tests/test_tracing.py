@@ -409,30 +409,27 @@ def test_block_lifecycle_span_tree(tracer):
 
 
 def test_hostpool_task_spans_nest_under_extend(tracer, monkeypatch):
-    """Host-fallback regime (no native): the memoized assembly's roots
-    batch fans over the hostpool, and each task's queue-wait + run spans
-    nest under the extend phase — the phase-tail gap made visible."""
-    from celestia_tpu.da import dah as dah_mod, eds_cache
+    """Host DAH leg with no native library: new_data_availability_header
+    fans the numpy roots over the hostpool, and each task's queue-wait +
+    run spans nest under the extend phase — the phase-tail gap made
+    visible."""
+    from celestia_tpu.da import dah as dah_mod
     from celestia_tpu.utils import hostpool
     from celestia_tpu.utils import native as native_mod
 
     if hostpool.cpu_threads() < 2:
         pytest.skip("needs a multi-worker pool for pool-fanned roots")
     monkeypatch.setattr(native_mod, "available", lambda: False)
-    monkeypatch.setattr(dah_mod, "_row_memo_applicable", lambda: True)
-    eds_cache.clear()
-    dah_mod.clear_row_memo()
+    monkeypatch.setattr(dah_mod, "_host_native_available", lambda: True)
     k = 4
     rng = np.random.default_rng(3)
-    a = rng.integers(0, 256, (k, k, 512), dtype=np.uint8)
-    b = a.copy()
-    b[0] = rng.integers(0, 256, (k, 512), dtype=np.uint8)  # 75% row reuse
-    dah_mod.extend_and_header(a)  # height H: populates the row memo
+    eds = dah_mod.ExtendedDataSquare(
+        rng.integers(0, 256, (2 * k, 2 * k, 512), dtype=np.uint8)
+    )
     tracing.clear()
     with tracing.block_span("prepare_proposal", height=2):
         with tracing.span("extend"):
-            dah_mod.extend_and_header(b)  # height H+1: memoized assembly
-    dah_mod.clear_row_memo()
+            dah_mod.new_data_availability_header(eds)
     tr = tracing.block_traces()[0]
     tree = tr.tree()
     extend = _find(tree, "extend")
@@ -468,12 +465,11 @@ def test_trace_determinism_same_chaos_seed(tracer):
     """Two runs of the same block sequence under the same chaos seed
     produce structurally identical span trees — names, parentage, span
     counts.  Durations differ; structure must not."""
-    from celestia_tpu.da import dah as dah_mod, eds_cache
+    from celestia_tpu.da import eds_cache
     from celestia_tpu.utils import faults
 
     def run_once():
         eds_cache.clear()
-        dah_mod.clear_row_memo()
         faults.disarm()
         # same seed => same injection schedule => same degraded paths
         faults.arm("lru.put", "fail_rate", rate=0.5, seed=1234)

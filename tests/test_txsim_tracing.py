@@ -19,7 +19,7 @@ from celestia_tpu.utils.secp256k1 import PrivateKey
 
 
 def test_signed_pfb_txs_pass_the_filter():
-    """txsim.signed_pfb_txs (bench.py, chip_smoke.py): round-robin
+    """txsim.signed_pfb_txs (chip_smoke.py): round-robin
     signers at consecutive sequences, one random blob each, every tx
     kept by the proposer's FilterTxs."""
     keys = [PrivateKey.from_seed(b"pfb-gen-%d" % i) for i in range(2)]

@@ -28,7 +28,7 @@ PY="${PY:-python}"
 TESTS=(
   "tests/test_leopard_codec.py::test_threaded_host_pipeline_byte_identical"
   "tests/test_leopard_codec.py::test_golden_parity_vectors_pin_leopard_bytes"
-  "tests/test_bench_smoke.py::test_threaded_extend_repair_dah_smoke"
+  "tests/test_host_pipeline.py::test_threaded_extend_repair_dah_smoke"
   "tests/test_golden_vectors.py::test_native_blob_commitments_batch"
 )
 

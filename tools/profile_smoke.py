@@ -2,8 +2,8 @@
 (`make profile-smoke`).
 
 Leg 1 (single process): one tiny-k testnode block with tracing AND the
-device track armed — the extension is forced through the jitted jax leg
-(the device path's code shape, on whatever backend is present) — and
+device track armed — the extension is forced through the device plane
+(the leg the chip runs, on whatever backend is present) — and
 asserts:
 
 * the merged Chrome trace is schema-valid and contains HOST spans and
@@ -62,7 +62,6 @@ def leg1() -> int:
     jax.config.update("jax_platforms", "cpu")
 
     from celestia_tpu.client.signer import Signer
-    from celestia_tpu.da import dah as dah_mod
     from celestia_tpu.da import eds_cache
     from celestia_tpu.node.server import NodeService
     from celestia_tpu.node.testnode import TestNode
@@ -70,13 +69,6 @@ def leg1() -> int:
     from celestia_tpu.utils import devprof, faults, timeseries, tracing
     from celestia_tpu.utils.secp256k1 import PrivateKey
     from celestia_tpu.utils.telemetry import validate_exposition
-
-    # force the jitted (device-shaped) extension leg: this process owns
-    # these module attributes; the native fused pipeline and the row
-    # memo would otherwise satisfy the tiny square host-side and no
-    # device dispatch would ever happen on a CPU backend
-    dah_mod._host_native_available = lambda: False
-    dah_mod._row_memo_applicable = lambda: False
 
     tracing.enable(4)
     tracing.clear()
@@ -147,9 +139,9 @@ def leg1() -> int:
     # a background thread — join it before reading the table)
     devprof.flush_compiles()
     prof = devprof.device_profile()
-    if "extend_and_roots" not in prof["kernels"]:
+    if "extend_levels" not in prof["kernels"]:
         print(
-            f"profile-smoke: no cost row for extend_and_roots "
+            f"profile-smoke: no cost row for extend_levels "
             f"(kernels: {sorted(prof['kernels'])}, notes: {prof['notes']})",
             file=sys.stderr,
         )
@@ -360,7 +352,13 @@ def leg2() -> int:
 def main(argv) -> int:
     legs = argv[1:] or ["--leg1", "--leg2"]
     if "--leg1" in legs:
-        rc = leg1()
+        from celestia_tpu.da import device_plane
+
+        # force the device plane (the leg the chip runs): the native
+        # fused pipeline would otherwise satisfy the tiny square
+        # host-side and no device dispatch would happen on a CPU backend
+        with device_plane.forced("on"):
+            rc = leg1()
         if rc != 0:
             return rc
     if "--leg2" in legs:

@@ -13,6 +13,7 @@ XLA executable per square size.  This runs twice per block per validator
 from __future__ import annotations
 
 import threading
+from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import lru_cache
 from typing import Tuple
@@ -265,7 +266,8 @@ def extend_and_header(
 
     A fault in leg 1 or 2 poisons that leg one-way (loud) and this very
     call falls through to the next, so the block being extended still
-    commits the same data root.
+    commits the same data root.  Inside :func:`extend_block_ahead` of the
+    same square, leg 1 awaits the program launched there.
     """
     from celestia_tpu.da import device_plane
     from celestia_tpu.utils import devprof, native
@@ -311,6 +313,30 @@ def extend_and_header(
     return eds, dah
 
 
+@contextmanager
+def extend_block_ahead(square: Square):
+    """Start :func:`extend_block`'s device leg for ``square`` now, so the
+    device computes while the caller's block runs on the host: an
+    :func:`extend_block` of the same square inside the block awaits this
+    launch (da/device_plane.py ``ahead``).  Off the device plane nothing
+    starts early, and the host legs run where the caller asks for the
+    extend.  A launch fault poisons the plane, as in
+    :func:`extend_and_header`.  Yields whether a program was launched."""
+    from celestia_tpu.da import device_plane
+
+    pending = None
+    if device_plane.enabled():
+        try:
+            pending = device_plane.begin_extend(_original_array(square))
+        except Exception as e:
+            device_plane.poison(f"device-resident extend failed: {e!r}")
+    if pending is None:
+        yield False
+        return
+    with device_plane.ahead(pending):
+        yield True
+
+
 def new_data_availability_header(eds: ExtendedDataSquare) -> DataAvailabilityHeader:
     """da.NewDataAvailabilityHeader parity: roots + hash from an existing EDS.
 
@@ -343,9 +369,13 @@ def new_data_availability_header(eds: ExtendedDataSquare) -> DataAvailabilityHea
 
 def extend_block(square: Square) -> Tuple[ExtendedDataSquare, DataAvailabilityHeader]:
     """app.ExtendBlock parity (extend_block.go:14-26): square -> (EDS, DAH)."""
+    return extend_and_header(_original_array(square))
+
+
+def _original_array(square: Square) -> np.ndarray:
+    """uint8[k,k,512], a view of the square's own (cached) array."""
     k = square.size
-    arr = square.to_array().reshape(k, k, SHARE_SIZE)
-    return extend_and_header(arr)
+    return square.to_array().reshape(k, k, SHARE_SIZE)
 
 
 # serializes the first computation of the min DAH; the PR 4 worker pool

@@ -45,6 +45,7 @@ from __future__ import annotations
 import os
 import threading
 from contextlib import contextmanager
+from contextvars import ContextVar
 from functools import lru_cache
 from typing import List, Optional, Sequence, Tuple
 
@@ -198,24 +199,30 @@ class DevicePlaneEntry:
         )
 
 
-def extend_and_header(square):
-    """The device-resident twin of da/dah.extend_and_header: square
-    uint8[k,k,512] -> (ExtendedDataSquare, DataAvailabilityHeader),
-    byte-identical to the host pipeline (the consensus-safety
-    requirement, pinned by tests/test_device_plane.py).
+class PendingExtend:
+    """An ``extend_levels`` run launched by :func:`begin_extend` and not
+    yet awaited: the program's un-awaited outputs, with what
+    :func:`finish_extend` needs to await, fetch and register them."""
 
-    D2H budget: 32 bytes (data root, eager) + 4k x 90 bytes (axis roots,
-    one lazily-issued tuple fetch for the host DAH object).  The EDS and
-    the level stacks stay on the device inside the returned
-    :class:`DevicePlaneEntry`, registered in eds_cache's device-handle
-    budget so process/commit and DAS serving find the block device-warm.
-    """
-    from celestia_tpu.da import eds_cache
-    from celestia_tpu.da.dah import DataAvailabilityHeader, ExtendedDataSquare
+    __slots__ = ("square", "k", "codec", "fn", "arr", "dispatch", "out")
 
+    def __init__(self, square, k, codec, fn, arr, dispatch, out):
+        self.square = square
+        self.k = k
+        self.codec = codec
+        self.fn = fn
+        self.arr = arr
+        self.dispatch = dispatch
+        self.out = out
+
+
+def begin_extend(square) -> PendingExtend:
+    """Upload the square uint8[k,k,512] and launch ``extend_levels``;
+    return without waiting.  The host is free until
+    :func:`finish_extend`."""
     k = int(square.shape[0])
     codec = _active_codec()
-    with tracing.span("extend.device_plane", k=k, codec=codec):
+    with tracing.span("extend.device_plane", k=k, codec=codec, stage="launch"):
         fn = _extend_levels_fn(k, codec)
         with tracing.span("extend.upload", bytes=k * k * 512):
             t0 = _clock()
@@ -228,10 +235,28 @@ def extend_and_header(square):
         d = devprof.dispatch("extend_levels", k=k, codec=codec)
         with tracing.span("extend.launch"):
             out = fn(arr)
+        d.launched()
+    return PendingExtend(square, k, codec, fn, arr, d, out)
+
+
+def finish_extend(
+    pending: PendingExtend,
+) -> Tuple["ExtendedDataSquare", "DataAvailabilityHeader"]:
+    """Await a :func:`begin_extend` run: fetch the data root and the
+    axis roots, assemble the DAH and register the device buffers in
+    eds_cache's device-handle budget.  Returns (EDS, DAH).  A launch
+    that is never finished registers nothing."""
+    from celestia_tpu.da import eds_cache
+    from celestia_tpu.da.dah import DataAvailabilityHeader, ExtendedDataSquare
+
+    k = pending.k
+    with tracing.span(
+        "extend.device_plane", k=k, codec=pending.codec, stage="finish"
+    ):
         # the host waits on the device here: the ONE eager hot-path D2H,
         # the 32-byte data root
         with tracing.span("extend.wait"):
-            eds_d, levels, root_levels = d.done(out)
+            eds_d, levels, root_levels = pending.dispatch.done(pending.out)
             data_root = bytes(devprof.fetch("data_root", root_levels[-1])[0])
         # axis roots, lazily fetched relative to the dispatch (they are
         # only needed to assemble the host DAH object) — ONE tuple fetch
@@ -250,8 +275,52 @@ def extend_and_header(square):
             eds_cache.put_device_entry(data_root, entry)
     # cost accounting OUTSIDE the traced span (da/dah.py placement
     # contract)
-    devprof.note_compile("extend_levels", fn, (arr,))
+    devprof.note_compile("extend_levels", pending.fn, (pending.arr,))
     return ExtendedDataSquare(eds_d), dah
+
+
+# the launch an enclosing :func:`ahead` block made, per thread/context
+_ahead: ContextVar[Optional[PendingExtend]] = ContextVar("extend_ahead", default=None)
+
+
+@contextmanager
+def ahead(pending: PendingExtend):
+    """Within the block, :func:`extend_and_header` of ``pending``'s
+    square awaits this launch instead of launching again: the caller
+    runs host work in the block while the device computes.  A launch
+    not awaited by the block's end is dropped and registers nothing."""
+    token = _ahead.set(pending)
+    try:
+        yield
+    finally:
+        _ahead.reset(token)
+
+
+def _same_array(a, b) -> bool:
+    """Whether two arrays view the same memory the same way."""
+    return a is b or a.__array_interface__ == b.__array_interface__
+
+
+def extend_and_header(square):
+    """The device-resident twin of da/dah.extend_and_header: square
+    uint8[k,k,512] -> (ExtendedDataSquare, DataAvailabilityHeader),
+    byte-identical to the host pipeline (the consensus-safety
+    requirement, pinned by tests/test_device_plane.py).
+    :func:`begin_extend` then :func:`finish_extend`, or only the finish
+    where an enclosing :func:`ahead` block launched this very square.
+
+    D2H budget: 32 bytes (data root, eager) + 4k x 90 bytes (axis roots,
+    one lazily-issued tuple fetch for the host DAH object).  The EDS and
+    the level stacks stay on the device inside the returned
+    :class:`DevicePlaneEntry`, registered in eds_cache's device-handle
+    budget so process/commit and DAS serving find the block device-warm.
+    """
+    pending = _ahead.get()
+    if pending is not None and _same_array(pending.square, square):
+        _ahead.set(None)
+    else:
+        pending = begin_extend(square)
+    return finish_extend(pending)
 
 
 @lru_cache(maxsize=4096)

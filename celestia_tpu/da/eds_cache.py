@@ -109,6 +109,10 @@ class EdsCache:
         the eviction line just because its reads never count."""
         return self._lru.peek(key)
 
+    def __contains__(self, key: bytes) -> bool:
+        """Membership only: no counters, no recency refresh."""
+        return key in self._lru
+
     def put(self, key: bytes, eds, dah) -> None:
         self._lru.put(key, (eds, dah))
 

@@ -15,6 +15,7 @@ arithmetic or the bit-exact device kernels.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib as _hashlib
 import time as _time
 from dataclasses import dataclass, field
@@ -907,7 +908,12 @@ class App:
         return kept
 
     def _extend_block_cached(
-        self, block_txs: List[bytes], square, leg: str
+        self,
+        block_txs: List[bytes],
+        square,
+        leg: str,
+        key: Optional[bytes] = None,
+        launched: bool = False,
     ) -> Tuple["dah_mod.ExtendedDataSquare", "dah_mod.DataAvailabilityHeader"]:
         """ExtendBlock through the content-addressed EDS cache.
 
@@ -918,7 +924,28 @@ class App:
         re-proposals and repeated gossip validations of one block all
         collapse to a lookup; everything else (ante, signatures, square
         reconstruction, the root comparison) still runs in the caller.
+
+        ProcessProposal passes the ``key`` it hashed before the ante, and
+        ``launched`` where :meth:`_launch_ahead` started the extend then.
         """
+        from celestia_tpu.da import eds_cache
+
+        if key is None:
+            key = self._eds_key(block_txs, square, leg)
+        args = {"stage": "finish"} if launched else {}
+        with tracing.span("extend", leg=leg, k=square.size, **args) as sp:
+            cached = eds_cache.get(key)
+            if cached is not None:
+                self.telemetry.incr(f"eds_cache_hit_{leg}")
+                sp.annotate(eds_cache="hit")
+                return cached
+            self.telemetry.incr(f"eds_cache_miss_{leg}")
+            sp.annotate(eds_cache="miss")
+            eds, dah = self._extend_square_routed(square)
+            eds_cache.put(key, eds, dah)
+            return eds, dah
+
+    def _eds_key(self, block_txs: List[bytes], square, leg: str) -> bytes:
         from celestia_tpu.da import eds_cache
         from celestia_tpu.ops import gf256 as _gf256
 
@@ -930,17 +957,32 @@ class App:
             )
             if tracing.enabled():
                 sp.annotate(bytes=sum(len(raw) for raw in block_txs))
-        with tracing.span("extend", leg=leg, k=square.size) as sp:
-            cached = eds_cache.get(key)
-            if cached is not None:
-                self.telemetry.incr(f"eds_cache_hit_{leg}")
-                sp.annotate(eds_cache="hit")
-                return cached
-            self.telemetry.incr(f"eds_cache_miss_{leg}")
-            sp.annotate(eds_cache="miss")
-            eds, dah = self._extend_square_routed(square)
-            eds_cache.put(key, eds, dah)
-            return eds, dah
+        return key
+
+    def _launch_ahead(
+        self, stack: contextlib.ExitStack, key: bytes, square
+    ) -> bool:
+        """ProcessProposal's early start of the extend that
+        :meth:`_extend_block_cached` will await: where the square is not
+        cached and no mesh takes it (:meth:`_extend_square_routed`'s
+        route), ``da/dah.extend_block_ahead`` launches the device plane's
+        program for the rest of ``stack``'s block, and it computes while
+        the host checks the txs (counted in ``extend_overlap_process``).
+        The membership test moves no cache counter or recency, and a
+        launch the block never awaits registers nothing: a proposal the
+        ante rejects leaves the caches as they were.  Returns whether a
+        program was launched."""
+        from celestia_tpu.da import eds_cache
+        from celestia_tpu.parallel import mesh as mesh_mod
+
+        k = square.size
+        if key in eds_cache.CACHE or mesh_mod.mesh_for_square(k) is not None:
+            return False
+        with tracing.span("extend", leg="process", k=k, stage="launch"):
+            launched = stack.enter_context(dah_mod.extend_block_ahead(square))
+        if launched:
+            self.telemetry.incr("extend_overlap_process")
+        return launched
 
     def _extend_square_routed(
         self, square
@@ -1225,44 +1267,61 @@ class App:
     def _process_proposal_traced(
         self, block_txs: List[bytes], square_size: int, data_root: bytes
     ) -> Tuple[bool, str]:
+        # strict reconstruction — NOT skippable on a cache hit: the
+        # square must be re-derivable from the tx bytes under the
+        # CURRENT size bound, and only that reconstruction makes the
+        # cached (txs -> EDS/DAH) mapping apply to this proposal.  It
+        # runs before the ante so a miss can launch the extend, which
+        # then computes while the host checks the txs; its verdicts
+        # (a raise, a size mismatch) still come after the ante's, so a
+        # proposal is rejected for the same reason as before.
         branch = self.store.branch()
         accounts = AccountKeeper(branch.store("auth"))
         bank = BankKeeper(branch.store("bank"))
         params = ParamsKeeper(branch.store("params"))
-        with tracing.span("decode_and_ante", txs=len(block_txs), cpu=True):
-            decoded = self._decode_proposal_txs(block_txs)
-            with tracing.span("ante.chain", txs=len(decoded), cpu=True):
-                for raw, tx, raw_inner, sig_ok, err in decoded:
-                    if err is not None:
-                        return False, f"invalid tx in proposal: {err}"
-                    ctx = AnteContext(
-                        tx=tx,
-                        raw_tx=raw_inner,
-                        accounts=accounts,
-                        bank=bank,
-                        params=params,
-                        chain_id=self.chain_id,
-                        app_version=self.app_version,
-                        sig_ok=sig_ok,
-                        height=self.next_height(),
-                        feegrant=FeeGrantKeeper(branch.store("feegrant")),
-                        time_ns=self.block_time_ns,
-                    )
-                    run_ante(ctx)
-        # strict reconstruction — NOT skippable on a cache hit: the
-        # square must be re-derivable from the tx bytes under the
-        # CURRENT size bound, and only that reconstruction makes the
-        # cached (txs -> EDS/DAH) mapping apply to this proposal
-        with tracing.span("square_build", txs=len(block_txs)):
-            square, re_txs, _ = construct_square(
-                block_txs, self.max_effective_square_size()
+        square = square_error = key = None
+        try:
+            with tracing.span("square_build", txs=len(block_txs)):
+                square, _, _ = construct_square(
+                    block_txs, self.max_effective_square_size()
+                )
+        except Exception as e:
+            square_error = e
+        with contextlib.ExitStack() as ahead:
+            launched = False
+            if square is not None and square.size == square_size:
+                key = self._eds_key(block_txs, square, "process")
+                launched = self._launch_ahead(ahead, key, square)
+            with tracing.span("decode_and_ante", txs=len(block_txs), cpu=True):
+                decoded = self._decode_proposal_txs(block_txs)
+                with tracing.span("ante.chain", txs=len(decoded), cpu=True):
+                    for raw, tx, raw_inner, sig_ok, err in decoded:
+                        if err is not None:
+                            return False, f"invalid tx in proposal: {err}"
+                        ctx = AnteContext(
+                            tx=tx,
+                            raw_tx=raw_inner,
+                            accounts=accounts,
+                            bank=bank,
+                            params=params,
+                            chain_id=self.chain_id,
+                            app_version=self.app_version,
+                            sig_ok=sig_ok,
+                            height=self.next_height(),
+                            feegrant=FeeGrantKeeper(branch.store("feegrant")),
+                            time_ns=self.block_time_ns,
+                        )
+                        run_ante(ctx)
+            if square_error is not None:
+                raise square_error
+            if square.size != square_size:
+                return False, (
+                    f"square size mismatch: computed {square.size}, "
+                    f"header says {square_size}"
+                )
+            _, dah = self._extend_block_cached(
+                block_txs, square, "process", key, launched
             )
-        if square.size != square_size:
-            return False, (
-                f"square size mismatch: computed {square.size}, "
-                f"header says {square_size}"
-            )
-        _, dah = self._extend_block_cached(block_txs, square, "process")
         if dah.hash != data_root:
             self.telemetry.incr("process_proposal_rejected_data_root")
             return False, (

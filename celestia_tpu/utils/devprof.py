@@ -198,6 +198,9 @@ class _NullDispatch:
 
     __slots__ = ()
 
+    def launched(self) -> None:
+        pass
+
     def done(self, out):
         return out
 
@@ -307,9 +310,16 @@ class Dispatch:
     across: one device-track span and one busy contribution per chip.
     All chips execute the collective program concurrently, so charging
     the full interval to each is the same queue-wait-plus-execution
-    upper bound the single-device bracket records."""
+    upper bound the single-device bracket records.
 
-    __slots__ = ("name", "args", "_t0", "_parent", "_multi")
+    Where the host does other work between the launch and :meth:`done`
+    (da/device_plane.py ``begin_extend``), :meth:`launched` stamps the
+    enqueue's return: ``enqueue_ms`` leaves that work out, and the device
+    span and busy time run from the launch's return, not from the moment
+    the host begins to wait — the same upper bound, with the program
+    running under the host's work counted."""
+
+    __slots__ = ("name", "args", "_t0", "_t_enq", "_parent", "_multi")
 
     def __init__(self, name: str, args: dict, multi: bool = False):
         self.name = name
@@ -317,11 +327,17 @@ class Dispatch:
         self._multi = multi
         self._parent = tracing.current()
         self._t0 = clock()
+        self._t_enq: Optional[float] = None
+
+    def launched(self) -> None:
+        """Stamp the enqueue's return (see the class docstring)."""
+        self._t_enq = clock()
 
     def done(self, out):
         import jax
 
-        t1 = clock()  # enqueue returned; device may still be running
+        # enqueue returned; device may still be running
+        t1 = clock() if self._t_enq is None else self._t_enq
         try:
             jax.block_until_ready(out)
         except Exception as e:

@@ -84,6 +84,29 @@ def test_device_track_span_inside_block_trace(tracer):
     assert any(n.startswith("device:") for n in names), names
 
 
+def test_launched_dispatch_charges_the_host_work_after_the_launch(tracer):
+    """A program launched, then left to run under other host work before
+    ``done``: busy time and the device span cover it from the launch's
+    return, and ``enqueue_ms`` leaves the host work out."""
+    import time
+
+    import jax.numpy as jnp
+
+    x = jnp.arange(8)
+    (x + 1).block_until_ready()  # compiled outside the bracket
+    with devprof.collect(), tracing.block_span("process_proposal", height=1):
+        d = devprof.dispatch("overlapped", k=1)
+        out = x + 1
+        d.launched()
+        time.sleep(0.05)  # the host's own work while the device runs
+        d.done(out)
+        prof = devprof.device_profile()
+    assert prof["device_busy_ms_total"] >= 50.0, prof["device_busy_ms_total"]
+    (span,) = [s for s in tracing.block_traces()[-1].spans if s.name == "device.overlapped"]
+    assert span.duration_ms >= 50.0
+    assert span.args["enqueue_ms"] < 50.0
+
+
 class _NoneMemDevice:
     platform = "cpu"
     id = 0

@@ -502,12 +502,15 @@ def _children(tr, parent):
     return [s for s in tr.spans if s.parent_id == parent.span_id]
 
 
-def test_process_proposal_spans_cover_the_block(tracer):
+def test_process_proposal_spans_cover_the_block(tracer, monkeypatch):
     """A cold ProcessProposal of multi-blob PFBs on the device plane:
     decode_and_ante splits into its four ante.* stages with their counts,
-    the device plane's host side into its five extend.* stages, the
-    block key hash has a span of its own beside extend, and the root's
-    own time is a small remainder."""
+    the device plane's host side into its five extend.* stages — the
+    upload and launch before the ante, the wait, fetch and assembly
+    after it — the block key hash has a span of its own beside extend,
+    and the root's own time is a small remainder."""
+    import jax
+
     from celestia_tpu.client import txsim
     from celestia_tpu.client.signer import Signer
     from celestia_tpu.da import device_plane, eds_cache, inclusion
@@ -540,6 +543,18 @@ def test_process_proposal_spans_cover_the_block(tracer):
         )
         assert ok, reason
 
+    # On the CPU backend the "device" is the host's own cores: the program
+    # launched before the ante would run on them beside the host thread and
+    # could preempt it between two spans.  Let it finish inside its launch
+    # span, as a chip computes without taking the host's cores.
+    begin_extend = device_plane.begin_extend
+
+    def begin_and_drain(square):
+        pending = begin_extend(square)
+        jax.block_until_ready(pending.out)
+        return pending
+
+    monkeypatch.setattr(device_plane, "begin_extend", begin_and_drain)
     with device_plane.forced("on"):
         prep = app.prepare_proposal(txs)
         cold_process(prep)  # compiles; the traced call below is warm
@@ -547,8 +562,18 @@ def test_process_proposal_spans_cover_the_block(tracer):
         cold_process(prep)
     tr = [t for t in tracing.block_traces() if t.name == "process_proposal"][-1]
     root = [s for s in tr.spans if s.span_id == tr.root_id][0]
-    top = {s.name: s for s in _children(tr, root)}
+    children = _children(tr, root)
+    top = {s.name: s for s in children}
     assert {"decode_and_ante", "square_build", "eds_cache.key", "extend"} <= set(top)
+    # the miss launches the extend before the ante and finishes it after
+    extends = [s for s in children if s.name == "extend"]
+    assert [s.args.get("stage") for s in extends] == ["launch", "finish"]
+    assert all(s.args["leg"] == "process" for s in extends)
+    assert extends[0].t1 <= top["decode_and_ante"].t0
+    assert top["decode_and_ante"].t1 <= extends[1].t0
+    assert extends[1].args["eds_cache"] == "miss"
+    counters = app.telemetry.summary()["counters"]
+    assert counters["extend_overlap_process"] == counters["eds_cache_miss_process"]
     assert top["eds_cache.key"].args["bytes"] == sum(len(t) for t in prep.block_txs)
     assert "cpu_ms" in top["decode_and_ante"].args
 
@@ -571,19 +596,23 @@ def test_process_proposal_spans_cover_the_block(tracer):
     assert by_name["ante.sig_verify"][0].args["cache_hits"] == 0
     assert by_name["ante.chain"][0].args["txs"] == 3
 
-    plane = [s for s in tr.spans if s.name == "extend.device_plane"]
-    assert plane, "the device plane must run the extend"
-    stages = {s.name: s for s in _children(tr, plane[0])}
-    assert {
-        "extend.upload", "extend.launch", "extend.wait",
-        "extend.fetch_roots", "extend.assemble",
-    } <= set(stages)
+    plane = {
+        s.args["stage"]: s for s in tr.spans if s.name == "extend.device_plane"
+    }
+    assert set(plane) == {"launch", "finish"}, "the device plane must run the extend"
+    assert plane["launch"].parent_id == extends[0].span_id
+    assert plane["finish"].parent_id == extends[1].span_id
+    stages = {s.name: s for s in _children(tr, plane["launch"])}
+    assert {"extend.upload", "extend.launch"} <= set(stages)
+    finish = {s.name: s for s in _children(tr, plane["finish"])}
+    assert {"extend.wait", "extend.fetch_roots", "extend.assemble"} <= set(finish)
+    stages.update(finish)
     k = prep.square_size
     assert k == 4
     assert stages["extend.upload"].args["bytes"] == k * k * 512
     assert stages["extend.fetch_roots"].args["bytes"] == 4 * k * 90
 
-    self_ms = root.duration_ms - sum(s.duration_ms for s in top.values())
+    self_ms = root.duration_ms - sum(s.duration_ms for s in children)
     assert self_ms < 0.1 * root.duration_ms, (self_ms, root.duration_ms)
 
 

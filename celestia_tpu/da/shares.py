@@ -187,34 +187,49 @@ def blob_shares_array(
     return arr
 
 
-def parse_sparse_shares(shares: Sequence[Share]) -> List[Tuple[Namespace, bytes]]:
-    """Reassemble (namespace, blob-bytes) sequences from sparse shares.
+def parse_sparse_array(arr: np.ndarray) -> List[Tuple[int, int, Namespace, bytes]]:
+    """Reassemble the blobs of sparse shares uint8[n, 512], touching
+    each share once as an array slice: ``(position of the first share,
+    share version, namespace, blob bytes)`` per blob, in order.
 
     Padding sequences (sequence length 0) are skipped.
     """
-    blobs: List[Tuple[Namespace, bytes]] = []
-    i = 0
-    while i < len(shares):
-        sh = shares[i]
-        if not sh.is_sequence_start:
-            raise ValueError(f"share {i}: expected sequence start")
-        seq_len = sh.sequence_len()
+    info = arr[:, NAMESPACE_SIZE]
+    starts = np.nonzero(info & 1)[0]
+    off = NAMESPACE_SIZE + SHARE_INFO_BYTES
+    lens = arr[starts, off : off + SEQUENCE_LEN_BYTES].astype(np.uint64)
+    lens = (lens << np.array([24, 16, 8, 0], dtype=np.uint64)).sum(axis=1)
+    blobs = []
+    nxt = 0  # the next share, which must start a sequence
+    for p, seq_len in zip(starts.tolist(), lens.tolist()):
+        if p != nxt:
+            raise ValueError(f"share {nxt}: expected sequence start")
         if seq_len == 0:  # padding share
-            i += 1
+            nxt = p + 1
             continue
-        ns = sh.namespace
-        data = bytearray(sh.sparse_payload())
-        i += 1
-        while len(data) < seq_len:
-            if i >= len(shares):
-                raise ValueError("truncated share sequence")
-            cont = shares[i]
-            if cont.is_sequence_start or cont.namespace.raw != ns.raw:
-                raise ValueError(f"share {i}: broken sequence continuation")
-            data.extend(cont.sparse_payload())
-            i += 1
-        blobs.append((ns, bytes(data[:seq_len])))
+        nxt = p + sparse_shares_needed(seq_len)
+        if nxt > len(arr):
+            raise ValueError("truncated share sequence")
+        ns = arr[p, :NAMESPACE_SIZE]
+        if (info[p + 1 : nxt] & 1).any() or (arr[p + 1 : nxt, :NAMESPACE_SIZE] != ns).any():
+            raise ValueError(f"share {p}: broken sequence continuation")
+        data = arr[p, off + SEQUENCE_LEN_BYTES :].tobytes() + arr[p + 1 : nxt, off:].tobytes()
+        blobs.append((p, int(info[p]) >> 1, Namespace(ns.tobytes()), data[:seq_len]))
+    if nxt != len(arr):
+        raise ValueError(f"share {nxt}: expected sequence start")
     return blobs
+
+
+def parse_sparse_shares(shares: Sequence[Share]) -> List[Tuple[Namespace, bytes]]:
+    """Reassemble (namespace, blob-bytes) sequences from sparse shares
+    (:func:`parse_sparse_array`).
+
+    Padding sequences (sequence length 0) are skipped.
+    """
+    if not shares:
+        return []
+    arr = np.frombuffer(b"".join(s.raw for s in shares), dtype=np.uint8)
+    return [(ns, data) for _p, _v, ns, data in parse_sparse_array(arr.reshape(len(shares), SHARE_SIZE))]
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,7 @@ import numpy as np
 from celestia_tpu.appconsts import (
     DEFAULT_SQUARE_SIZE_UPPER_BOUND,
     DEFAULT_SUBTREE_ROOT_THRESHOLD,
+    NAMESPACE_SIZE,
     SUPPORTED_SHARE_VERSIONS,
     round_up_power_of_two,
 )
@@ -37,6 +38,7 @@ from celestia_tpu.da.blob import (
     BlobTx,
     IndexWrapper,
     unmarshal_blob_tx,
+    unmarshal_index_wrapper,
 )
 from celestia_tpu.da.namespace import (
     Namespace,
@@ -51,7 +53,7 @@ from celestia_tpu.da.shares import (
     blob_shares_array,
     padding_share,
     parse_compact_shares,
-    parse_sparse_shares,
+    parse_sparse_array,
     shares_to_array,
     split_txs_into_shares,
 )
@@ -414,18 +416,51 @@ def construct(
     return b.export()
 
 
+def _compact_txs(arr: np.ndarray, namespace: Namespace) -> List[bytes]:
+    """The tx list of ``namespace``'s compact share sequence."""
+    rows = np.nonzero((arr[:, :NAMESPACE_SIZE] == np.frombuffer(namespace.raw, np.uint8)).all(axis=1))[0]
+    return parse_compact_shares([Share(arr[i].tobytes()) for i in rows])
+
+
+def _user_blobs(arr: np.ndarray) -> List[Tuple[int, int, Namespace, bytes]]:
+    """``(first share index, share version, namespace, data)`` of every
+    blob in the user namespaces of a square uint8[k*k, 512], in square
+    order (``shares.parse_sparse_array``).  A namespace is a user's where
+    its first 28 bytes are neither all 0x00 (primary reserved) nor all
+    0xFF (secondary reserved)."""
+    head = arr[:, : NAMESPACE_SIZE - 1]
+    rows = np.nonzero(~((head == 0).all(axis=1) | (head == 0xFF).all(axis=1)))[0]
+    return [(int(rows[p]), v, ns, data) for p, v, ns, data in parse_sparse_array(arr[rows])]
+
+
 def extract_txs_and_blobs(
     square: Square,
 ) -> Tuple[List[bytes], List[bytes], List[Tuple[Namespace, bytes]]]:
     """Parse a square back into (normal txs, wrapped PFB txs, blobs)."""
-    tx_shares = [s for s in square.shares if s.namespace.raw == TRANSACTION_NAMESPACE.raw]
-    pfb_shares = [s for s in square.shares if s.namespace.raw == PAY_FOR_BLOB_NAMESPACE.raw]
-    blob_shares = [
-        s
-        for s in square.shares
-        if s.namespace.is_usable_by_users()
-    ]
-    txs = parse_compact_shares(tx_shares) if tx_shares else []
-    pfbs = parse_compact_shares(pfb_shares) if pfb_shares else []
-    blobs = parse_sparse_shares(blob_shares)
-    return txs, pfbs, blobs
+    arr = square.to_array()
+    txs = _compact_txs(arr, TRANSACTION_NAMESPACE)
+    pfbs = _compact_txs(arr, PAY_FOR_BLOB_NAMESPACE)
+    return txs, pfbs, [(ns, data) for _i, _v, ns, data in _user_blobs(arr)]
+
+
+def deconstruct(square: Square) -> List[bytes]:
+    """go-square ``Deconstruct``: the block's txs from its square alone,
+    normal txs first, then one BlobTx envelope per PFB in the order of
+    the PAY_FOR_BLOB namespace — the order :func:`build` gives a block
+    whose normal txs come first.  Each envelope is marshalled again
+    (``BlobTx.marshal``) from its IndexWrapper's tx and the blobs that
+    start at its share indexes (:func:`extract_txs_and_blobs`' parse).
+    Raises ValueError on a square the builder could not have laid out:
+    a PFB that is no IndexWrapper, or an index where no blob starts."""
+    arr = square.to_array()
+    by_start = {i: Blob(ns, data, version) for i, version, ns, data in _user_blobs(arr)}
+    out = _compact_txs(arr, TRANSACTION_NAMESPACE)
+    for raw in _compact_txs(arr, PAY_FOR_BLOB_NAMESPACE):
+        wrapper = unmarshal_index_wrapper(raw)
+        if wrapper is None:
+            raise ValueError("a PAY_FOR_BLOB share sequence holds a tx that is no IndexWrapper")
+        missing = [i for i in wrapper.share_indexes if i not in by_start]
+        if missing:
+            raise ValueError(f"no blob starts at share indexes {missing[:8]}")
+        out.append(BlobTx(wrapper.tx, tuple(by_start[i] for i in wrapper.share_indexes)).marshal())
+    return out

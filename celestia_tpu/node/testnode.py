@@ -891,6 +891,15 @@ class TestNode:
         with self._service_lock:
             return self.app.process_proposal(block_txs, square_size, data_root)
 
+    def repair_block(
+        self, data_root: bytes, dah, shares, available
+    ) -> Tuple[bool, str]:
+        """Full-node half: rebuild a block from the shares gathered for
+        it, checked against the header's data root and DAH, and register
+        it for serving (``App.repair_block``).  Handed no txs."""
+        with self._service_lock:
+            return self.app.repair_block(data_root, dah, shares, available)
+
     def cons_commit(
         self,
         block_txs: List[bytes],

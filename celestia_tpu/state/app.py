@@ -32,7 +32,7 @@ from celestia_tpu.appconsts import (
 from celestia_tpu.da import dah as dah_mod
 from celestia_tpu.da.blob import BlobTx, unmarshal_blob_tx
 from celestia_tpu.da.inclusion import warm_commitments
-from celestia_tpu.da.square import Square, build as build_square, construct as construct_square
+from celestia_tpu.da.square import Square, build as build_square, construct as construct_square, deconstruct
 from celestia_tpu.state import app_versions
 from celestia_tpu.state.ante import AnteContext, AnteError, GasMeter, run_ante
 from celestia_tpu.state.auth import AccountKeeper
@@ -1238,6 +1238,156 @@ class App:
             square=square,
             wrappers=wrappers,
         )
+
+    # ------------------------------------------------------------------
+    # Repair — a full node rebuilding a block from the shares it gathered
+    # ------------------------------------------------------------------
+
+    def repair_block(
+        self, data_root: bytes, dah, shares, available
+    ) -> Tuple[bool, str]:
+        """A full node's reconstruction of a block it holds only in part
+        (celestia-node share/eds/retriever.go: rsmt2d ``Repair``, then
+        go-square ``Deconstruct``).  It is handed what a full node has:
+        the header's data root, its DAH, the shares uint8[2k, 2k, 512] it
+        gathered and the mask bool[2k, 2k] of those it holds (the other
+        cells are never read) — never the block's txs.
+
+        In order: the DAH must hash to the data root; the shares are
+        repaired and checked (one Reed–Solomon codeword, agreeing with
+        every provided share, every one of the 4k axes hashing to the
+        DAH's root); the txs are recovered from Q0 (:func:`deconstruct`)
+        and must lay the same Q0 out again (``construct``); then the
+        block is registered in the EDS cache under its txs' key and, on
+        the device plane, under its data root, so DAS serving finds it
+        on the chip.  Each failure rejects and registers nothing, with a
+        counter: ``repair_rejected_dah``, ``repair_rejected_mask`` (the
+        mask cannot repair, or does not fit the DAH),
+        ``repair_rejected_byzantine``, ``repair_rejected_roots``,
+        ``repair_rejected_layout``.
+
+        Where the device plane is on, one device program
+        (``rs.launch_repair``) repairs, checks and builds the NMT levels,
+        and the host fetches the verdicts, the roots and Q0 only
+        (counter ``repair_device``); a mask peeling in more than
+        ``rs._MAX_DEVICE_PHASES`` phases, or a plane that is off,
+        repairs on the host (``rs.repair_square``) and registers by
+        extending Q0 through the one extend route (``repair_host``).
+        Traced, the call is a ``repair_block`` block trace (args ``k``,
+        ``available``, ``withheld``, ``phases``, ``leg``)."""
+        avail = np.asarray(available, dtype=bool)
+        held = int(avail.sum())
+        with tracing.block_span(
+            "repair_block",
+            height=self.next_height(),
+            k=len(dah.row_roots) // 2,
+            available=held,
+            withheld=int(avail.size) - held,
+        ) as root:
+            return self._repair_block_traced(data_root, dah, shares, avail, root)
+
+    def _repair_block_traced(
+        self, data_root: bytes, dah, shares, avail: np.ndarray, root
+    ) -> Tuple[bool, str]:
+        from celestia_tpu.da import device_plane, eds_cache
+        from celestia_tpu.ops import gf256 as _gf256
+        from celestia_tpu.ops import rs
+
+        k = len(dah.row_roots) // 2
+        with tracing.span("repair.dah_check"):
+            try:
+                dah.validate_basic()
+                dah_ok = dah.hash == data_root
+            except ValueError:
+                dah_ok = False
+        if not dah_ok:
+            self.telemetry.incr("repair_rejected_dah")
+            return False, "repair rejected: the DAH does not hash to the data root"
+        shares = np.asarray(shares, dtype=np.uint8)
+        if shares.shape != (2 * k, 2 * k, SHARE_SIZE) or avail.shape != (2 * k, 2 * k):
+            self.telemetry.incr("repair_rejected_mask")
+            return False, (
+                f"repair rejected: shares {shares.shape} and mask {avail.shape} "
+                f"do not fit the DAH's {2 * k}x{2 * k} square"
+            )
+        want = np.frombuffer(
+            b"".join(dah.row_roots + dah.col_roots), dtype=np.uint8
+        ).reshape(2, 2 * k, -1)
+        repaired = None
+        try:
+            try:
+                pending = rs.launch_repair(
+                    shares, avail, want, device=device_plane.enabled()
+                )
+                root.annotate(phases=pending.phases)
+                if pending.out is not None:
+                    repaired = rs.finish_repair(pending, fetch_q0=True)
+            except (rs.ByzantineError, ValueError):
+                raise
+            except Exception as e:
+                # a device fault: the plane goes off one-way and this
+                # block repairs on the host
+                device_plane.poison(f"device repair failed: {e!r}")
+            if repaired is None:
+                with tracing.span("repair.host"):
+                    q0 = rs.repair_square(shares, avail, want[0], want[1])[:k, :k]
+            else:
+                q0 = repaired.q0
+        except rs.RootMismatchError as e:
+            self.telemetry.incr("repair_rejected_roots")
+            return False, f"repair rejected: {e}"
+        except rs.ByzantineError as e:
+            self.telemetry.incr("repair_rejected_byzantine")
+            return False, f"repair rejected: {e}"
+        except ValueError as e:
+            self.telemetry.incr("repair_rejected_mask")
+            return False, f"repair rejected: {e}"
+        leg = "host" if repaired is None else "device"
+        root.annotate(leg=leg)
+        square = Square(size=k, array=np.ascontiguousarray(q0).reshape(k * k, SHARE_SIZE))
+        with tracing.span("repair.deconstruct") as sp:
+            try:
+                block_txs = deconstruct(square)
+                again, _, wrappers = construct_square(
+                    block_txs, self.max_effective_square_size()
+                )
+                laid_out = again.size == k and np.array_equal(
+                    again.to_array(), square.to_array()
+                )
+                sp.annotate(
+                    txs=len(block_txs),
+                    blobs=sum(len(w.share_indexes) for w in wrappers),
+                )
+            except ValueError:
+                laid_out = False
+        if not laid_out:
+            self.telemetry.incr("repair_rejected_layout")
+            return False, (
+                "repair rejected: the square's txs do not lay it out again"
+            )
+        with tracing.span("repair.register"):
+            if repaired is None:
+                self._extend_block_cached(block_txs, square, "repair")
+            else:
+                key = eds_cache.make_key(
+                    block_txs, k, self.app_version, _gf256.active_codec()
+                )
+                rr, cc = repaired.roots
+                header = dah_mod.DataAvailabilityHeader(
+                    tuple(r.tobytes() for r in rr),
+                    tuple(c.tobytes() for c in cc),
+                    repaired.data_root,
+                )
+                eds_cache.put_device_entry(
+                    data_root,
+                    device_plane.DevicePlaneEntry(
+                        k, data_root, repaired.eds, repaired.levels,
+                        repaired.root_levels,
+                    ),
+                )
+                eds_cache.put(key, dah_mod.ExtendedDataSquare(repaired.eds), header)
+        self.telemetry.incr(f"repair_{leg}")
+        return True, ""
 
     # ------------------------------------------------------------------
     # ProcessProposal — process_proposal.go:24-157

@@ -10,10 +10,9 @@ second ``TestNode`` built from the same genesis runs ``process_proposal``
 on every committed block cold (EDS cache cleared) and commits it to the
 same app hash; every data root and all 4k axis roots are checked byte
 for byte against the native host reference; share proofs fetched over
-gRPC are verified against the data root; one k=128 repair with 25% of
-the cells withheld runs through ``rs.repair_square_device``.  The run
-fails unless every extend went through ``extend.device_plane`` on a
-``tpu`` device track and no poison or degradation was recorded.
+gRPC are verified against the data root.  The run fails unless every
+extend went through ``extend.device_plane`` on a ``tpu`` device track
+and no poison or degradation was recorded.
 
 ``python chip_smoke.py --chips 4`` runs only the sharded path of a
 four-chip host: one k=128 block through ``App._extend_square_routed`` on
@@ -212,8 +211,8 @@ def run_one_chip(ks=(64, 128), platform="tpu", block_interval_s=2.0):
     from celestia_tpu.node.remote import RemoteNode
     from celestia_tpu.node.server import NodeServer
     from celestia_tpu.node.testnode import TestNode
-    from celestia_tpu.ops import gf256, rs
-    from celestia_tpu.utils import devprof, tracing
+    from celestia_tpu.ops import gf256
+    from celestia_tpu.utils import tracing
     from celestia_tpu.utils.secp256k1 import PrivateKey
 
     k_default, k_cap = ks
@@ -222,19 +221,16 @@ def run_one_chip(ks=(64, 128), platform="tpu", block_interval_s=2.0):
 
     # the big programs of the run, compiled side by side: the extend at
     # every size a traffic block can take (k/2 too: traffic split over
-    # two blocks can leave one that small) and the repair at the cap.
-    # Uncommitted shapes, as the node passes its arrays: the same cache
-    # key as the jitted calls that follow.
+    # two blocks can leave one that small).  Uncommitted shapes, as the
+    # node passes its arrays: the same cache key as the jitted calls that
+    # follow.
     codec = gf256.active_codec()
     warm = sorted({1, k_default // 2, k_default, k_cap})
-    n2 = 2 * k_cap
-    avail = np.random.default_rng(SEED + 1).random((n2, n2)) >= 0.25
     jobs = [
         (f"extend_levels k={s}", device_plane._extend_levels_fn(s, codec),
          (jax.ShapeDtypeStruct((s, s, 512), jnp.uint8),))
         for s in warm if s > 1
     ]
-    jobs.append((f"repair_verify k={k_cap}", *rs.repair_program(avail)))
     timed("precompile (wall, programs in parallel)", precompile, jobs)
 
     # warm every size the run will extend BEFORE serving (cli start's
@@ -321,7 +317,7 @@ def run_one_chip(ks=(64, 128), platform="tpu", block_interval_s=2.0):
     checked = {}
     for blk in node.blocks:
         h, k = blk.header.height, blk.header.square_size
-        _sq, _w, ref_eds, rr, cc, droot = reference_extend(blk.txs, k)
+        _sq, _w, _eds, rr, cc, droot = reference_extend(blk.txs, k)
         check(blk.header.data_hash == droot, f"height {h}: committed data root differs from the reference")
         art = node._eds_cache.get(h)
         if art is not None:
@@ -344,8 +340,6 @@ def run_one_chip(ks=(64, 128), platform="tpu", block_interval_s=2.0):
         )
         check(app_hash == blk.header.app_hash, f"height {h}: follower app hash differs")
         checked[k] = checked.get(k, 0) + 1
-        if k == k_cap:
-            cap_block = (ref_eds, rr, cc)
     counters = follower.app.telemetry.summary()["counters"]
     check(counters.get("eds_cache_hit_process", 0) == 0, "follower hit the EDS cache")
     log(
@@ -353,18 +347,6 @@ def run_one_chip(ks=(64, 128), platform="tpu", block_interval_s=2.0):
         f"all 4k axis roots byte-identical to the host reference on both nodes; "
         f"follower app hashes agree"
     )
-
-    # repair at the cap with 25% of the cells withheld
-    ref_eds, rr, cc = cap_block
-    for label in ("cold (compile included)", "warm"):
-        repaired = timed(
-            f"repair k={n2 // 2} 25% withheld {label}",
-            rs.repair_square_device, ref_eds, avail, rr, cc,
-        )
-        check(np.array_equal(np.asarray(repaired), ref_eds), "repaired square differs from the reference")
-    log(f"repair k={n2 // 2} with {int((~avail).sum())} of {n2 * n2} cells withheld: square and roots verified")
-    n_repair = devprof.device_profile()["dispatches"].get("rs_repair_verify", 0)
-    check(n_repair == 2, f"{n_repair} of 2 repairs ran on the device")
 
     check_device_path(platform)
 

@@ -274,9 +274,9 @@ def test_repair_device_detects_byzantine():
 
 
 def test_repair_program_is_the_program_repair_dispatches(monkeypatch):
-    """rs.repair_program hands an AOT caller (chip_smoke.py's precompile)
-    the very program, and argument shapes, that repair_square_device
-    then dispatches — so its compile lands on the same cache entry."""
+    """rs.repair_program hands an AOT caller the very program, and
+    argument shapes, that repair_square_device then dispatches — so its
+    compile lands on the same cache entry."""
     from celestia_tpu.ops import nmt as nmt_ops
 
     rng = np.random.default_rng(37)

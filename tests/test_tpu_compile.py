@@ -100,7 +100,7 @@ def test_repair_verify_k32(shape):
         gf256.active_codec(),
     )
     c = fn.lower(
-        shape((n2, n2, 512)), shape(avail.shape, jnp.bool_),
+        shape((n2, n2, 512)), shape(avail.shape, jnp.bool_), shape((2, n2, 90)),
         shape(rk.shape), shape(rm.shape, jnp.bool_),
         shape(ck.shape), shape(cm.shape, jnp.bool_),
     ).compile()
